@@ -56,6 +56,10 @@ def main() -> None:
     args = ap.parse_args()
     only = args.only or args.scenario
 
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
     from . import (
         complexity,
         fused,

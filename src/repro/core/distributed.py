@@ -85,9 +85,9 @@ class ShardedKernelOperator(LinearOperator):
 
     def matmul(self, M):
         from repro.distributed.sharding import (
-            compat_shard_map,
             current_mesh,
             mesh_axis_sizes,
+            unchecked_shard_map,
         )
 
         squeeze = M.ndim == 1
@@ -122,7 +122,7 @@ class ShardedKernelOperator(LinearOperator):
             out = _local_block_matmul(kernel, X_loc, X_full, M_full, chunk)
             return out.astype(jnp.float32)
 
-        out = compat_shard_map(
+        out = unchecked_shard_map(
             body,
             mesh,
             in_specs=(tuple(P() for _ in kern_leaves), P(None, None), P(axes, None)),
@@ -153,7 +153,7 @@ class ShardedKernelOperator(LinearOperator):
         over the full (n, t) state (and their per-pass collectives under
         pjit) collapse into one region with a 3-array gather + one O(t)
         psum."""
-        from repro.distributed.sharding import compat_shard_map, mesh_axis_sizes
+        from repro.distributed.sharding import mesh_axis_sizes, unchecked_shard_map
 
         s2 = jnp.float32(0.0) if sigma2 is None else jnp.asarray(sigma2)
         if s2.ndim:
@@ -204,7 +204,7 @@ class ShardedKernelOperator(LinearOperator):
         def step(U, R, D, V, alpha, beta, gamma):
             state_spec = P(*([None] * (U.ndim - 2)), axes, None)
             rep = P(*([None] * (U.ndim - 1)))
-            return compat_shard_map(
+            return unchecked_shard_map(
                 body,
                 mesh,
                 in_specs=(

@@ -58,7 +58,7 @@ from .health import (
 )
 from .linear_operator import LinearOperator
 from .mbcg import mbcg, tridiag_matrices
-from .precision import precision_compute_dtype, validate_precision
+from .precision import f32_matmuls, precision_compute_dtype, validate_precision
 from .preconditioner import IdentityPreconditioner, build_preconditioner
 from .slq import logdet_from_mbcg, slq_quadrature
 
@@ -602,6 +602,7 @@ def _engine_forward(
     return state
 
 
+@f32_matmuls
 def inv_quad_logdet(
     op: LinearOperator,
     y: jax.Array,
@@ -623,6 +624,7 @@ def inv_quad_logdet(
         residuals = (op, state.solve_y, state.probe_solves, state.precond_probes, key)
         return (state.inv_quad, state.logdet), residuals
 
+    @f32_matmuls  # transposed outside inv_quad_logdet's own call
     def _bwd(residuals, cotangents):
         op, u, probe_solves, pinv_z, key = residuals
         g_iq, g_ld = cotangents
@@ -649,6 +651,7 @@ def inv_quad_logdet(
     return _iql(op, y, key)
 
 
+@f32_matmuls
 def engine_state(
     op: LinearOperator,
     y: jax.Array,
@@ -692,6 +695,7 @@ def engine_state(
     )
 
 
+@f32_matmuls
 def build_posterior_cache(
     op: LinearOperator,
     y: jax.Array,
@@ -816,6 +820,7 @@ def _compact_basis(basis: jax.Array, gram: jax.Array, max_m: int):
     return basis @ keep, jnp.diag(jnp.sqrt(lam))
 
 
+@f32_matmuls
 def extend_posterior_cache(
     op: LinearOperator,
     y: jax.Array,
@@ -990,11 +995,13 @@ def _extend_cache_once(
     return new_cache, report
 
 
+@f32_matmuls
 def cached_mean(cache: PosteriorCache, Kxs: jax.Array) -> jax.Array:
     """Posterior mean k(X*, X) K̂⁻¹y from the cache — O(n·s), no CG."""
     return Kxs.T @ cache.alpha
 
 
+@f32_matmuls
 def cached_inv_quad(cache: PosteriorCache, Kxs: jax.Array) -> jax.Array:
     """k*ᵀK̂⁻¹k* per column of Kxs via the Rayleigh–Ritz cache — O(n·m)."""
     if cache.basis is None:
@@ -1024,6 +1031,7 @@ def marginal_log_likelihood(
     return -0.5 * (inv_quad + logdet + n * jnp.log(2.0 * jnp.pi))
 
 
+@f32_matmuls
 def solve(op, B, settings: BBMMSettings = BBMMSettings(), *, precond=None):
     """Plain preconditioned solve K̂⁻¹B (prediction-time helper).
 

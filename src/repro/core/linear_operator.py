@@ -1216,9 +1216,9 @@ def _sharded_xla_panel_fused_step(
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import (
-        compat_shard_map,
         ordered_psum,
         row_shard_spec,
+        unchecked_shard_map,
     )
 
     axes = op.data_axes
@@ -1250,7 +1250,7 @@ def _sharded_xla_panel_fused_step(
     state_spec = row_shard_spec(U.ndim, axes)
     rep = P(*([None] * (U.ndim - 1)))
     x_spec = P(*([None] * op.X.ndim))
-    return compat_shard_map(
+    return unchecked_shard_map(
         body,
         mesh,
         in_specs=(
@@ -1290,8 +1290,8 @@ def _sharded_panel_matmul(op, M, mesh, shards):
 
     from repro.core.precision import as_jnp_dtype
     from repro.distributed.sharding import (
-        compat_shard_map,
         row_shard_spec,
+        unchecked_shard_map,
     )
 
     axes = op.data_axes
@@ -1335,7 +1335,7 @@ def _sharded_panel_matmul(op, M, mesh, shards):
         )
 
     x_spec = P(*([None] * Xdat.ndim))
-    out = compat_shard_map(
+    out = unchecked_shard_map(
         body,
         mesh,
         in_specs=(
@@ -1424,7 +1424,7 @@ def _sharded_partitioned_matmul_fwd(op, M):
 def _sharded_partitioned_matmul_bwd(res, ct):
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import compat_shard_map, row_shard_spec
+    from repro.distributed.sharding import row_shard_spec, unchecked_shard_map
 
     op, M = res
     mesh = op.mesh
@@ -1462,7 +1462,7 @@ def _sharded_partitioned_matmul_bwd(res, ct):
     x_spec = P(*([None] * op.X.ndim))
     ct_spec = row_shard_spec(M.ndim, axes)
     rep_m = P(*([None] * M.ndim))
-    kb_leaves, X_bar, M_bar = compat_shard_map(
+    kb_leaves, X_bar, M_bar = unchecked_shard_map(
         body,
         mesh,
         in_specs=(

@@ -116,9 +116,9 @@ def pivoted_cholesky_sharded(
       L: (n, k), row-sharded over ``axes``.
     """
     from repro.distributed.sharding import (
-        compat_shard_map,
         current_mesh,
         mesh_axis_sizes,
+        unchecked_shard_map,
     )
 
     mesh = mesh if mesh is not None else current_mesh()
@@ -183,7 +183,7 @@ def pivoted_cholesky_sharded(
         )
         return L
 
-    return compat_shard_map(
+    return unchecked_shard_map(
         body,
         mesh,
         in_specs=(tuple(P() for _ in leaves), P(axes)),

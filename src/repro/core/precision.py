@@ -17,6 +17,13 @@ Two policies, named from the user-facing end down to the kernel:
     preserved by a periodic f32 residual refresh inside mBCG (see
     ``repro.core.mbcg``).
 
+On TPU, XLA's default f32 matmul is a single bf16 pass, which would
+quietly break the "everything else stays f32" half of both policies.  The
+engine entry points therefore run under :func:`f32_matmuls` (full f32
+contraction for f32 operands; the explicit bf16 stages cast their operands
+and are unaffected), and the Pallas kernel names its MXU precision itself.
+On CPU both are no-ops.
+
 ``compute_dtype`` is the low-level knob threaded through the Pallas kernel,
 ``prescale_inputs``, the ``KernelOperator`` family and
 ``LinearOperator.with_compute_dtype``; ``precision`` is the end-to-end knob
@@ -26,6 +33,9 @@ vocabulary — ``normalize_compute_dtype`` maps between them.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 
 PRECISIONS = ("highest", "mixed")
@@ -74,3 +84,16 @@ def is_reduced(compute_dtype) -> bool:
     their ``compute_dtype`` field through this (never ``== "bfloat16"``) so
     the 'mixed' alias means the same thing on every construction path."""
     return normalize_compute_dtype(compute_dtype) == "bfloat16"
+
+
+def f32_matmuls(fn):
+    """Decorator: trace/run ``fn`` with f32 matmuls at full f32 precision
+    (``jax.default_matmul_precision("highest")``) — see the module
+    docstring for why the engine needs it on TPU."""
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return wrapped

@@ -75,9 +75,9 @@ def pipeline_forward(
         # all-reduce so every stage returns the full outputs (simple API)
         return jax.lax.psum(outputs, axis) / 1.0
 
-    from .sharding import compat_shard_map
+    from .sharding import unchecked_shard_map
 
-    fn = compat_shard_map(
+    fn = unchecked_shard_map(
         per_stage,
         mesh,
         in_specs=(P(axis), P()),

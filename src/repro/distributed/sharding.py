@@ -15,55 +15,20 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# The jax version this repo's compat shims are written against.  The whole
-# suite passes on this pin through the legacy branches below
-# (compat_shard_map's jax.experimental fallback, current_mesh's
-# thread_resources probe, use_mesh's legacy context path, mesh_axis_sizes's
-# devices.shape fallback).  tests/test_jax_pin.py fails loudly when the
-# installed jax moves off this pin: per ROADMAP, that is the moment to
-# DELETE the legacy branches (shrink the shims, don't grow them), migrate
-# the `with mesh:` test contexts to jax.set_mesh, and bump this constant.
-PINNED_JAX = "0.4.37"
-
 
 def current_mesh():
-    """The live mesh, across jax versions: prefer the new abstract-mesh API,
-    fall back to the legacy ``with mesh:`` thread resources."""
-    gm = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gm is not None:
-        mesh = gm()
-        if mesh is not None and mesh.axis_names:
-            return mesh
-        # fall through: a legacy `with mesh:` context sets thread_resources
-        # without the abstract mesh, even on jax versions that have both
-    try:
-        from jax._src.mesh import thread_resources
-
-        pm = thread_resources.env.physical_mesh
-        if pm.axis_names:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The mesh installed by ``jax.set_mesh`` (as an AbstractMesh), or None
+    outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the live mesh, across jax
-    versions (jax.set_mesh vs the legacy Mesh context manager)."""
-    sm = getattr(jax, "set_mesh", None)
-    if sm is not None:
-        return sm(mesh)
-    return mesh  # legacy Mesh is itself a context manager
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (jax.shard_map vs experimental)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+def unchecked_shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-manual-axes check (the BBMM
+    bodies mix replicated and per-device values by design)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def ordered_psum(x, axes=("data",)):
@@ -110,10 +75,8 @@ def has_model_axis():
 
 
 def mesh_axis_sizes(mesh):
-    """{axis_name: size} for either mesh flavor (AbstractMesh has
-    axis_sizes but no .devices; legacy Mesh the reverse)."""
-    sizes = getattr(mesh, "axis_sizes", None) or mesh.devices.shape
-    return dict(zip(mesh.axis_names, sizes))
+    """{axis_name: size} of a Mesh or AbstractMesh."""
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def axis_size(name):

@@ -76,6 +76,7 @@ class ExactGP(KrylovCachePredictor):
     # precond_rank=0 (the pivoted-Cholesky solve cannot fuse; mbcg raises).
     # None follows ``settings.fuse_cg``; an explicit value wins.
     fuse_cg: bool | None = None
+    ard: bool = False  # one lengthscale per input dim in init_params
 
     def __post_init__(self):
         if self.precision is not None:
@@ -90,9 +91,9 @@ class ExactGP(KrylovCachePredictor):
         """Exact GP has no hyperparameter-free geometry: data IS X."""
         return X
 
-    def init_params(self, X, ard: bool = False, key=None):
+    def init_params(self, X, key=None):
         d = _input_dim(X)
-        ell0 = jnp.zeros((d,) if ard else ()) + _inv_softplus(jnp.float32(0.5))
+        ell0 = jnp.zeros((d,) if self.ard else ()) + _inv_softplus(jnp.float32(0.5))
         return {
             "raw_lengthscale": ell0,
             "raw_outputscale": _inv_softplus(jnp.float32(1.0)),

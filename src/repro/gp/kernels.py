@@ -25,6 +25,7 @@ from repro.core.linear_operator import (
     LinearOperator,
     _mixed_matmul,
     _register,
+    _xla_panel_matmul,
     static_field,
 )
 from repro.core.precision import is_reduced, normalize_compute_dtype
@@ -104,6 +105,45 @@ class DeepKernel:
         return self.base.diag(X)
 
 
+@jax.custom_vjp
+def _pallas_matmul(op, M):
+    """K(X, X) @ M through the Pallas kernel, with hand-wired gradients.
+
+    ``op`` is a ``KernelOperator(mode="pallas")`` or its prepared form; the
+    primal is its ``_pallas_forward`` (one ``pallas_call``).  ``pallas_call``
+    has no usable JVP rule (lowering it dies on ``assert env.grid_context
+    is not None``, compiled for TPU and interpreted alike), so the VJP
+    differentiates the checkpointed XLA panel stream of the same product
+    instead: one (panel_rows × n) kernel slab live at a time, never the
+    dense K — the same seam as the partitioned path's
+    ``_partitioned_matmul``."""
+    return op._pallas_forward(M)
+
+
+def _pallas_matmul_fwd(op, M):
+    return op._pallas_forward(M), (op, M)
+
+
+def _pallas_matmul_bwd(res, ct):
+    from repro.kernels.kernel_matmul.ops import choose_panel_rows
+
+    op, M = res
+    p = choose_panel_rows(op.X.shape[0])
+
+    def ref(kernel, X, m):
+        return _xla_panel_matmul(kernel, X, X, m, p, compute_dtype=op.compute_dtype)
+
+    _, vjp = jax.vjp(ref, op.kernel, op.X, M)
+    kern_bar, X_bar, M_bar = vjp(ct)
+    # a prepared operator's pre-scaled Xs is a pure function of
+    # (kernel.lengthscale, X), both already accounted for: zero cotangent
+    extra = {"Xs": jnp.zeros_like(op.Xs)} if hasattr(op, "Xs") else {}
+    return dataclasses.replace(op, kernel=kern_bar, X=X_bar, **extra), M_bar
+
+
+_pallas_matmul.defvjp(_pallas_matmul_fwd, _pallas_matmul_bwd)
+
+
 @_register
 @dataclasses.dataclass(frozen=True)
 class KernelOperator(LinearOperator):
@@ -154,9 +194,7 @@ class KernelOperator(LinearOperator):
         elif self.mode == "blocked":
             out = self._blocked_matmul(M)
         elif self.mode == "pallas":
-            from repro.kernels.kernel_matmul.ops import kernel_matmul
-
-            out = kernel_matmul(self.kernel, self.X, M, self.compute_dtype)
+            out = _pallas_matmul(self, M)
         elif self.mode == "pallas_sharded":
             from repro.kernels.kernel_matmul.ops import sharded_kernel_matmul
 
@@ -173,6 +211,11 @@ class KernelOperator(LinearOperator):
 
             out = jax.lax.with_sharding_constraint(out, P(("pod", "data"), None))
         return out[:, 0] if squeeze else out
+
+    def _pallas_forward(self, M):
+        from repro.kernels.kernel_matmul.ops import kernel_matmul
+
+        return kernel_matmul(self.kernel, self.X, M, self.compute_dtype)
 
     def _mesh(self):
         if self.mesh is not None:
@@ -300,6 +343,9 @@ class PreparedPallasKernelOperator(LinearOperator):
         return self.X.dtype
 
     def matmul(self, M):
+        return _pallas_matmul(self, M)
+
+    def _pallas_forward(self, M):
         from repro.kernels.kernel_matmul.ops import fused_kernel_matmul_prescaled
 
         return fused_kernel_matmul_prescaled(

@@ -59,7 +59,7 @@ from repro.core import (
     extend_posterior_cache,
     solve as bbmm_solve,
 )
-from repro.core.precision import precision_compute_dtype
+from repro.core.precision import f32_matmuls, precision_compute_dtype
 
 #: The structural surface every GP model exposes (checked, without
 #: isinstance, by tests/test_serving.py::TestProtocolConformance).
@@ -158,6 +158,7 @@ class KrylovCachePredictor:
             compute_dtype=precision_compute_dtype(self.settings.precision),
         )
 
+    @f32_matmuls
     def predict_cached(self, params, data, cache, Xstar, *, full_cov=False):
         """Serve mean + variance from a PosteriorCache — zero CG iterations.
 
@@ -180,6 +181,7 @@ class KrylovCachePredictor:
         var = kern.diag(Xstar) - cached_inv_quad(cache, Kxs)
         return mean, jnp.clip(var, 1e-8) + self.noise(params)
 
+    @f32_matmuls
     def predict(self, params, data, y, Xstar, *, full_cov=False, key=None):
         """Posterior mean and (diagonal) variance at Xstar (Eq. 1).
 

@@ -22,14 +22,12 @@ Robustness (the training leg of the solve-health layer):
 
   * non-finite ``X``/``y`` are rejected up front with an actionable error —
     one NaN row would otherwise poison every step silently;
-  * the known jax-0.4.37 Pallas interpret-mode jvp gap (``pallas_call``'s
-    jvp rule dies on a bare ``assert env.grid_context is not None`` under
-    ``jax.value_and_grad``) is detected on the first step and the model is
-    LOUDLY degraded to ``mode="dense"`` training — one warning naming the
-    bug and the override — instead of surfacing an opaque AssertionError
-    from deep inside jax (``mode="pallas_partitioned"`` is NOT affected:
-    its custom VJP re-streams row-panels under ``jax.checkpoint``, so it
-    trains natively on any backend);
+  * the model trains in the mode it was given: ``mode="pallas"`` and
+    ``mode="pallas_partitioned"`` launch the Pallas kernel in the forward
+    pass and differentiate a checkpointed XLA panel stream in their custom
+    VJPs (``pallas_call`` itself has no usable JVP rule), so a fault in
+    either raises — the fit never switches to a dense K behind the
+    caller's back;
   * every step's loss is checked for finiteness on the host, under the
     model's ``settings.on_failure`` policy: ``raise`` fails the fit,
     ``degrade`` retries the SAME step from the pre-step parameters at
@@ -52,21 +50,6 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.health import SolveFailure, SolveHealthWarning
 from repro.optim import adam
-
-#: substrings identifying the jax 0.4.37 interpret-mode pallas jvp failure
-#: (jax/_src/pallas/core.py `assert env.grid_context is not None`, reached
-#: via _pallas_call_jvp_rule) — matched against the exception traceback.
-_PALLAS_JVP_MARKERS = ("pallas",)
-
-
-def _is_pallas_jvp_gap(err: BaseException) -> bool:
-    """Is this the known pallas-interpret jvp AssertionError (vs a real one)?"""
-    import traceback
-
-    if not isinstance(err, AssertionError):
-        return False
-    tb = "".join(traceback.format_exception(type(err), err, err.__traceback__))
-    return any(marker in tb for marker in _PALLAS_JVP_MARKERS)
 
 
 def _require_finite(name: str, arr) -> None:
@@ -134,39 +117,13 @@ def fit_gp(
 
     n = y.shape[-1]
     history = []
-    pallas_degraded = False
     precision_degraded = False
     i = 0
     while i < steps:
         key, sub = jax.random.split(key)
         t_step = time.perf_counter()
-        try:
-            params_new, opt_new, loss = step(params, opt, sub)
-            loss_f = float(loss)  # host sync — the step is done here
-        except AssertionError as e:
-            if (
-                not pallas_degraded
-                and getattr(model, "mode", None) == "pallas"
-                and _is_pallas_jvp_gap(e)
-            ):
-                warnings.warn(
-                    "fit_gp: jax 0.4.37's interpret-mode pallas_call has no "
-                    "working jvp rule (its jvp path dies on `assert "
-                    "env.grid_context is not None` in jax/_src/pallas/core.py"
-                    "), so mode='pallas' cannot train under value_and_grad "
-                    "on this jax pin.  Degrading this fit to mode='dense' "
-                    "training — same kernel, same MLL, dense matmul; "
-                    "serve/predict with the pallas model afterwards, or "
-                    "pass mode='dense' explicitly to silence this warning.",
-                    SolveHealthWarning,
-                    stacklevel=2,
-                )
-                pallas_degraded = True
-                model = dataclasses.replace(model, mode="dense")
-                data = model.prepare_inputs(X)
-                step = make_step(model, data)
-                continue  # retry the SAME step index with the dense model
-            raise
+        params_new, opt_new, loss = step(params, opt, sub)
+        loss_f = float(loss)  # host sync — the step is done here
         if obs.active() is not None:
             # per-step training telemetry for gp_top during long fits
             mname = type(model).__name__

@@ -100,6 +100,15 @@ from repro.core.precision import as_jnp_dtype, normalize_compute_dtype
 _BATCH_OUT_VMEM_BYTES = 4 * 1024 * 1024
 
 
+def _mxu_precision(mxu_dtype):
+    """Explicit MXU precision: f32 operands get full f32 contraction
+    (Mosaic's and XLA's default on TPU may take fewer bf16 passes), bf16
+    operands their one native pass whatever the ambient default."""
+    if mxu_dtype == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
+
+
 def _apply_stationary(kernel_type: str, d2, outputscale):
     """Map squared distances → kernel values (VPU element-wise stage)."""
     if kernel_type == "rbf":
@@ -134,6 +143,7 @@ def _masked_kernel_tile(
         x1.astype(mxu_dtype),
         x2.astype(mxu_dtype),
         (((1,), (1,)), ((), ())),
+        precision=_mxu_precision(mxu_dtype),
         preferred_element_type=jnp.float32,
     )
     d2 = jnp.maximum(n1 + n2.T - 2.0 * inner, 0.0)
@@ -159,6 +169,7 @@ def _tile_rhs_product(k_tile, m, j, bm, n_cols, mxu_dtype):
         k_tile.astype(mxu_dtype),
         m.astype(mxu_dtype),
         (((1,), (0,)), ((), ())),
+        precision=_mxu_precision(mxu_dtype),
         preferred_element_type=jnp.float32,
     )
 
@@ -428,6 +439,7 @@ def _fused_cg_step_kernel(
         k_tile.astype(mxu_dtype),
         dcol.astype(mxu_dtype),
         (((1,), (0,)), ((), ())),
+        precision=_mxu_precision(mxu_dtype),
         preferred_element_type=jnp.float32,
     )
 

@@ -281,7 +281,7 @@ def sharded_kernel_matmul_prescaled(
     execution with no extra machinery.  Under the mixed policy M is cast to
     bf16 *before* the all-gather, so the one collective moves half the bytes.
     """
-    from repro.distributed.sharding import compat_shard_map, mesh_axis_sizes, row_shard_spec
+    from repro.distributed.sharding import mesh_axis_sizes, row_shard_spec, unchecked_shard_map
 
     compute_dtype = normalize_compute_dtype(compute_dtype)
     squeeze = M.ndim == 1
@@ -315,7 +315,7 @@ def sharded_kernel_matmul_prescaled(
             compute_dtype=compute_dtype,
         )
 
-    out = compat_shard_map(
+    out = unchecked_shard_map(
         body,
         mesh,
         in_specs=(P(None, None), row_shard_spec(M.ndim, axes), P()),
@@ -683,10 +683,10 @@ def sharded_fused_cg_step_prescaled(
     the panel decomposition matches, i.e. when panel_rows divides the band
     height)."""
     from repro.distributed.sharding import (
-        compat_shard_map,
         mesh_axis_sizes,
         ordered_psum,
         row_shard_spec,
+        unchecked_shard_map,
     )
 
     compute_dtype = normalize_compute_dtype(compute_dtype)
@@ -746,7 +746,7 @@ def sharded_fused_cg_step_prescaled(
         return Un, Rn, Dn, Vn, red
 
     state_spec = row_shard_spec(U.ndim, axes)
-    return compat_shard_map(
+    return unchecked_shard_map(
         body,
         mesh,
         in_specs=(

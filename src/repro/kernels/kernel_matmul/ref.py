@@ -3,11 +3,19 @@
 import jax.numpy as jnp
 
 
-def kernel_matmul_ref(X, M, lengthscale, outputscale, sigma2, *, kernel_type="rbf"):
-    """(K(X,X) + σ²I) @ M, materialized — the correctness reference."""
+def kernel_matmul_ref(
+    X, M, lengthscale, outputscale, sigma2, *, kernel_type="rbf", rows=None
+):
+    """(K(X,X) + σ²I) @ M, materialized — the correctness reference.
+
+    ``rows`` (an index array) keeps only those output rows, materializing
+    the (len(rows), n) block of K instead of all of it."""
     Xs = X / lengthscale
-    n1 = jnp.sum(Xs * Xs, -1)
-    d2 = jnp.maximum(n1[:, None] + n1[None, :] - 2.0 * (Xs @ Xs.T), 0.0)
+    r = jnp.arange(X.shape[0]) if rows is None else jnp.asarray(rows)
+    Xr = Xs[r]
+    n1 = jnp.sum(Xr * Xr, -1)
+    n2 = jnp.sum(Xs * Xs, -1)
+    d2 = jnp.maximum(n1[:, None] + n2[None, :] - 2.0 * (Xr @ Xs.T), 0.0)
     if kernel_type == "rbf":
         K = outputscale * jnp.exp(-0.5 * d2)
     else:
@@ -22,5 +30,5 @@ def kernel_matmul_ref(X, M, lengthscale, outputscale, sigma2, *, kernel_type="rb
             K = outputscale * (1.0 + a + a * a / 3.0) * jnp.exp(-a)
         else:
             raise ValueError(kernel_type)
-    K = K + sigma2 * jnp.eye(X.shape[0], dtype=K.dtype)
+    K = K + sigma2 * (r[:, None] == jnp.arange(X.shape[0])[None, :]).astype(K.dtype)
     return (K @ M.astype(K.dtype)).astype(jnp.float32)

@@ -35,7 +35,6 @@ from repro.distributed.sharding import (
     cache_shardings,
     p_batch,
     params_shardings,
-    use_mesh,
 )
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (
@@ -148,7 +147,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool, use_scan=True, cf
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle, step, args, shardings, out_shardings, donate = _eval_shapes(
             cfg, shape, use_scan=use_scan
         )
@@ -312,7 +311,7 @@ def gp_cell(arch: str, *, multi_pod: bool, opts: str = "") -> dict:
             new = jax.tree.map(lambda a, b: a - 0.1 * b, params, g)
             return new, loss
 
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(p_spec, P(), P(axes), P()),

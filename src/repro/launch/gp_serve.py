@@ -69,6 +69,7 @@ from repro.gp import (
     MultitaskGP,
     to_long_format,
 )
+from repro.launch.compile_cache import configure_compile_cache
 from repro.serving import CircuitBreaker, PosteriorSession
 
 MODELS = ("exact", "sgpr", "ski", "dkl", "blr", "multitask")
@@ -646,6 +647,7 @@ def main(argv=None):
                     "finished drill before the process exits)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     server = None
     holder: dict = {}

@@ -14,8 +14,8 @@ Covers the acceptance criteria:
     consistent cache's answer;
   * non-finite inputs are rejected with actionable errors before any
     session/fit mutation;
-  * ``fit_gp`` degrades the jax-0.4.37 pallas-jvp gap loudly to dense
-    training;
+  * ``fit_gp`` trains ``mode="pallas"`` through the kernel's custom VJP
+    and never switches to dense training;
   * the end-to-end ``--chaos`` threaded drill completes with zero
     unhandled exceptions, >=1 precision escalation, >=1 degraded query.
 """
@@ -446,18 +446,36 @@ class TestFitGP:
         with pytest.raises(ValueError, match="X contains"):
             fit_gp(gp, X.at[0, 0].set(jnp.nan), jnp.zeros((6,)), steps=1)
 
-    def test_pallas_jvp_gap_degrades_loudly_to_dense(self):
-        key = jax.random.PRNGKey(0)
-        X = jax.random.uniform(key, (24, 1))
-        y = jnp.sin(4 * X[:, 0])
-        gp = ExactGP(
-            mode="pallas",
-            settings=BBMMSettings(num_probes=2, max_cg_iters=10),
-        )
-        with pytest.warns(SolveHealthWarning, match="grid_context"):
-            params, hist = gp.fit(X, y, steps=2)
-        assert len(hist) == 2
-        assert all(np.isfinite(h) for h in hist)
+    def test_pallas_trains_through_kernel_without_dense_fallback(self):
+        """mode="pallas" MLL gradients (custom VJP around the Pallas
+        launch) match mode="dense", and fit_gp keeps the pallas model
+        through every step — no warning, no switch to a dense K."""
+        X = jax.random.uniform(jax.random.PRNGKey(0), (48, 2))
+        y = jnp.sin(4 * X[:, 0]) + 0.5 * jnp.cos(3 * X[:, 1])
+        s = BBMMSettings(num_probes=4, max_cg_iters=60, cg_tol=1e-6, precond_rank=3)
+        key = jax.random.PRNGKey(1)
+        grads = {}
+        for mode in ("dense", "pallas"):
+            gp = ExactGP(kernel_type="matern52", mode=mode, ard=True, settings=s)
+            grads[mode] = jax.grad(gp.loss)(gp.init_params(X), X, y, key)
+        for k in grads["dense"]:
+            np.testing.assert_allclose(
+                np.asarray(grads["pallas"][k]), np.asarray(grads["dense"][k]),
+                rtol=1e-3, atol=1e-4,
+            )
+
+        traced_modes = []
+
+        class ModeSpy(ExactGP):
+            def loss(self, params, data, y, key):
+                traced_modes.append(self.mode)
+                return super().loss(params, data, y, key)
+
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", SolveHealthWarning)
+            params, hist = fit_gp(ModeSpy(mode="pallas", settings=s), X, y, steps=3)
+        assert traced_modes and set(traced_modes) == {"pallas"}, traced_modes
+        assert len(hist) == 3 and all(np.isfinite(h) for h in hist)
         assert all(
             bool(jnp.all(jnp.isfinite(v)))
             for v in jax.tree_util.tree_leaves(params)

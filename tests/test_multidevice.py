@@ -35,15 +35,16 @@ class TestShardedGP:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from jax.sharding import PartitionSpec as P
             from repro.core import ShardedKernelOperator
             from repro.gp import KernelOperator, RBFKernel
 
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             kern = RBFKernel(lengthscale=jnp.float32(0.5), outputscale=jnp.float32(1.2))
             X = jax.random.normal(jax.random.PRNGKey(0), (64, 3))
             M = jax.random.normal(jax.random.PRNGKey(1), (64, 5))
-            with mesh:
+            with jax.set_mesh(mesh):
                 op = ShardedKernelOperator(kernel=kern, X=X, data_axes=("data",), chunk=16)
                 out = jax.jit(op.matmul)(M)
             ref = KernelOperator(kernel=kern, X=X, mode="dense").matmul(M)
@@ -56,6 +57,7 @@ class TestShardedGP:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from jax.sharding import PartitionSpec as P
             from repro.core import (AddedDiagOperator, BBMMSettings,
                                     ShardedKernelOperator, marginal_log_likelihood)
@@ -73,8 +75,8 @@ class TestShardedGP:
 
             g_dense = jax.grad(mll_dense)(jnp.float32(0.7))
 
-            mesh = jax.make_mesh((8,), ("data",))
-            with mesh:
+            mesh = make_mesh((8,), ("data",))
+            with jax.set_mesh(mesh):
                 def mll_shard(ell):
                     kern = RBFKernel(lengthscale=ell, outputscale=jnp.float32(1.0))
                     op = AddedDiagOperator(
@@ -92,12 +94,13 @@ class TestShardedGP:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.gp import KernelOperator, RBFKernel, MaternKernel
             from repro.kernels.kernel_matmul.ops import (
                 fused_kernel_matmul, sharded_kernel_matmul)
 
             assert jax.device_count() == 8
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             X = jax.random.normal(jax.random.PRNGKey(0), (96, 3))
             M = jax.random.normal(jax.random.PRNGKey(1), (96, 5))
             for kern in [
@@ -113,7 +116,7 @@ class TestShardedGP:
                 np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                            rtol=1e-5, atol=1e-5)
                 # operator-facing path, jitted, mesh from context
-                with mesh:
+                with jax.set_mesh(mesh):
                     op = KernelOperator(kernel=kern, X=X, mode="pallas_sharded")
                     out2 = jax.jit(op.matmul)(M)
                 np.testing.assert_allclose(np.asarray(out2), np.asarray(ref),
@@ -129,17 +132,18 @@ class TestShardedGP:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.core import (AddedDiagOperator, BBMMSettings, DenseOperator,
                                     build_preconditioner, marginal_log_likelihood,
                                     pivoted_cholesky_dense, pivoted_cholesky_sharded)
             from repro.gp import KernelOperator, RBFKernel
 
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             kern = RBFKernel(lengthscale=jnp.float32(0.5), outputscale=jnp.float32(1.2))
             X = jax.random.normal(jax.random.PRNGKey(0), (96, 3))
             K = kern(X, X)
             L_ref = pivoted_cholesky_dense(K, 6)
-            with mesh:
+            with jax.set_mesh(mesh):
                 L_sh = pivoted_cholesky_sharded(DenseOperator(K), 6)
             np.testing.assert_allclose(np.asarray(L_sh), np.asarray(L_ref), atol=1e-5)
 
@@ -148,7 +152,7 @@ class TestShardedGP:
             op = AddedDiagOperator(KernelOperator(kernel=kern, X=X, mode="dense"), 0.1)
             y = jnp.sin(X @ jnp.ones(3))
             s = BBMMSettings(num_probes=8, max_cg_iters=64, precond_rank=5, cg_tol=1e-9)
-            with mesh:
+            with jax.set_mesh(mesh):
                 P = jax.jit(lambda: build_preconditioner(op, 5))()
                 # same row access, replicated build: the sharding must be
                 # numerically invisible (dense-K references are fragile here:
@@ -164,7 +168,7 @@ class TestShardedGP:
             # indivisible n falls back to the replicated build (no error)
             X2 = jax.random.normal(jax.random.PRNGKey(2), (97, 3))
             op2 = AddedDiagOperator(KernelOperator(kernel=kern, X=X2, mode="dense"), 0.1)
-            with mesh:
+            with jax.set_mesh(mesh):
                 P2 = build_preconditioner(op2, 4)
             assert P2.L.shape == (97, 4)
             print("OK")
@@ -176,10 +180,11 @@ class TestShardedGP:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.core import AddedDiagOperator, BBMMSettings, marginal_log_likelihood
             from repro.gp import KernelOperator, RBFKernel
 
-            mesh = jax.make_mesh((4,), ("data",))
+            mesh = make_mesh((4,), ("data",))
             X = jax.random.normal(jax.random.PRNGKey(0), (64, 3))
             y = jnp.sin(X @ jnp.ones(3))
             key = jax.random.PRNGKey(1)
@@ -189,7 +194,7 @@ class TestShardedGP:
             mll_dense = marginal_log_likelihood(
                 AddedDiagOperator(KernelOperator(kernel=kern, X=X, mode="dense"), 0.1),
                 y, key, s)
-            with mesh:
+            with jax.set_mesh(mesh):
                 op = AddedDiagOperator(
                     KernelOperator(kernel=kern, X=X, mode="pallas_sharded"), 0.1)
                 mll_shard = marginal_log_likelihood(op, y, key, s)
@@ -205,14 +210,15 @@ class TestTrainStepSharded:
         run_with_devices(
             """
             import jax, jax.numpy as jnp
+            from repro.launch.mesh import make_mesh
             from repro.configs import get_config
             from repro.distributed.sharding import params_shardings, named_shardings
             from repro.models import build_model, make_train_step
 
             cfg = get_config("llama3.2-1b").reduced(num_heads=4, num_kv_heads=2, vocab_size=512)
             bundle = build_model(cfg)
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
-            with mesh:
+            mesh = make_mesh((4, 2), ("data", "model"))
+            with jax.set_mesh(mesh):
                 params = bundle.init(jax.random.PRNGKey(0))
                 specs = params_shardings(params, bundle.stacked_paths)
                 params = jax.tree.map(
@@ -234,13 +240,14 @@ class TestTrainStepSharded:
         run_with_devices(
             """
             import jax, jax.numpy as jnp
+            from repro.launch.mesh import make_mesh
             from repro.configs import get_config
             from repro.models import build_model, make_train_step
 
             cfg = get_config("granite-moe-1b-a400m").reduced(num_experts=4, top_k=2, vocab_size=512)
             bundle = build_model(cfg)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
-            with mesh:
+            mesh = make_mesh((2, 4), ("data", "model"))
+            with jax.set_mesh(mesh):
                 params = bundle.init(jax.random.PRNGKey(0))
                 step, init_opt = make_train_step(bundle, lr=1e-3)
                 opt = init_opt(params)
@@ -257,10 +264,11 @@ class TestPipelineParallel:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.distributed.pipeline import pipeline_forward
 
             S, M, mb, d = 4, 8, 4, 16
-            mesh = jax.make_mesh((S,), ("stage",))
+            mesh = make_mesh((S,), ("stage",))
             ws = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) * 0.3
 
             def stage_fn(w, x):
@@ -283,6 +291,7 @@ class TestElasticRestore:
         run_with_devices(
             """
             import tempfile, jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from jax.sharding import PartitionSpec as P, NamedSharding
             from repro.checkpoint.checkpointer import Checkpointer
 
@@ -290,11 +299,11 @@ class TestElasticRestore:
             with tempfile.TemporaryDirectory() as d:
                 ck = Checkpointer(d)
                 # save from an 8-way sharded layout
-                mesh8 = jax.make_mesh((8,), ("data",))
+                mesh8 = make_mesh((8,), ("data",))
                 sharded = jax.device_put(tree["w"], NamedSharding(mesh8, P("data", None)))
                 ck.save(0, {"w": sharded})
                 # restore onto a 2-way mesh (elastic downsize)
-                mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+                mesh2 = make_mesh((2, 4), ("data", "model"))
                 target = {"w": NamedSharding(mesh2, P("model", "data"))}
                 out = ck.restore(0, tree, shardings=target)
                 np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(tree["w"]))
@@ -312,16 +321,17 @@ class TestBf16Tiles:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.gp import KernelOperator, RBFKernel
 
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             kern = RBFKernel(lengthscale=jnp.float32(0.5), outputscale=jnp.float32(1.0))
             X = jax.random.normal(jax.random.PRNGKey(0), (64, 3))
             M = jax.random.normal(jax.random.PRNGKey(1), (64, 4))
             Mb = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 4))
             ref = KernelOperator(kernel=kern, X=X, mode="dense").matmul(M)
             ref_b = KernelOperator(kernel=kern, X=X, mode="dense").matmul(Mb)
-            with mesh:
+            with jax.set_mesh(mesh):
                 op = KernelOperator(kernel=kern, X=X, mode="pallas_sharded")
                 o16 = op.with_compute_dtype("mixed").matmul(M)
                 rel = float(jnp.linalg.norm(o16 - ref) / jnp.linalg.norm(ref))
@@ -340,14 +350,15 @@ class TestBf16Tiles:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.core import ShardedKernelOperator
             from repro.gp import RBFKernel
 
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             kern = RBFKernel(lengthscale=jnp.float32(0.5), outputscale=jnp.float32(1.0))
             X = jax.random.normal(jax.random.PRNGKey(0), (64, 3))
             M = jax.random.normal(jax.random.PRNGKey(1), (64, 4))
-            with mesh:
+            with jax.set_mesh(mesh):
                 f32 = ShardedKernelOperator(kernel=kern, X=X, data_axes=("data",), chunk=16)
                 b16 = ShardedKernelOperator(kernel=kern, X=X, data_axes=("data",), chunk=16,
                                             compute_dtype="bfloat16")
@@ -370,15 +381,16 @@ class TestFusedCGSharded:
             """
             import dataclasses
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.core import AddedDiagOperator, BBMMSettings, engine_state, mbcg
             from repro.core.mbcg import xla_cg_step
             from repro.gp import KernelOperator, RBFKernel
 
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             kern = RBFKernel(lengthscale=jnp.float32(0.5), outputscale=jnp.float32(1.2))
             X = jax.random.normal(jax.random.PRNGKey(0), (64, 3))
             y = jnp.sin(X @ jnp.ones(3))
-            with mesh:
+            with jax.set_mesh(mesh):
                 op = AddedDiagOperator(
                     KernelOperator(kernel=kern, X=X, mode="pallas_sharded",
                                    data_axes=("data",)), 0.1)
@@ -431,6 +443,7 @@ class TestMultitaskSharded:
         run_with_devices(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.core import (
                 BBMMSettings,
                 KroneckerAddedDiagOperator,
@@ -439,7 +452,7 @@ class TestMultitaskSharded:
             )
             from repro.gp import KernelOperator, RBFKernel
 
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             kern = RBFKernel(lengthscale=jnp.float32(0.5),
                              outputscale=jnp.float32(1.1))
             T, n = 4, 64
@@ -459,7 +472,7 @@ class TestMultitaskSharded:
 
             ref_op = multitask_op("dense")
             ref = ref_op.matmul(M)
-            with mesh:
+            with jax.set_mesh(mesh):
                 op = multitask_op("pallas_sharded")
                 out = op.matmul(M)
                 # prepare() recurses into the sharded data kernel: the CG

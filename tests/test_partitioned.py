@@ -281,6 +281,7 @@ class TestSharded:
         """8-CPU-device panel bands vs single-device streaming: bitwise."""
         body = """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import PartitionedKernelOperator, panel_accounting
         from repro.gp import RBFKernel
 
@@ -289,7 +290,7 @@ class TestSharded:
         X = jax.random.normal(jax.random.PRNGKey(0), (n, 4))
         kern = RBFKernel(lengthscale=jnp.float32(0.7), outputscale=jnp.float32(1.3))
         M = jax.random.normal(jax.random.PRNGKey(1), (n, 3))
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         for backend in ("pallas", "xla"):
             single = PartitionedKernelOperator(
                 kernel=kern, X=X, panel_rows=100, backend=backend, data_axes=())
@@ -308,6 +309,7 @@ class TestSharded:
     def test_ambient_mesh_context_shards(self):
         body = """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import PartitionedKernelOperator, panel_accounting
         from repro.gp import RBFKernel
 
@@ -317,8 +319,8 @@ class TestSharded:
         M = jax.random.normal(jax.random.PRNGKey(1), (n, 2))
         op = PartitionedKernelOperator(kernel=kern, X=X, panel_rows=64, backend="xla")
         ref = op.matmul(M)  # no mesh resolvable: single-device
-        mesh = jax.make_mesh((8,), ("data",))
-        with mesh:
+        mesh = make_mesh((8,), ("data",))
+        with jax.set_mesh(mesh):
             with panel_accounting() as launches:
                 out = op.matmul(M)
         assert launches[0].sharded and launches[0].devices == 8
@@ -528,6 +530,7 @@ class TestShardedFused:
         body = """
         import warnings
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import (AddedDiagOperator, BBMMSettings,
                                 PartitionedKernelOperator, collect, engine_state)
         from repro.gp import RBFKernel
@@ -540,7 +543,7 @@ class TestShardedFused:
         key = jax.random.PRNGKey(5)
         s = BBMMSettings(num_probes=2, max_cg_iters=25, precond_rank=0,
                          cg_tol=1e-4, fuse_cg=True)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         for backend in ("xla", "pallas"):
             single = AddedDiagOperator(PartitionedKernelOperator(
                 kernel=kern, X=X, panel_rows=96, backend=backend,
@@ -568,6 +571,7 @@ class TestShardedFused:
         body = """
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import BBMMSettings, PartitionedKernelOperator
         from repro.gp import ExactGP, KernelOperator, RBFKernel
 
@@ -575,7 +579,7 @@ class TestShardedFused:
         n = 512
         X = jax.random.normal(jax.random.PRNGKey(0), (n, 4))
         M = jax.random.normal(jax.random.PRNGKey(1), (n, 2))
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
 
         def loss(ell, backend, use_mesh):
             kern = RBFKernel(lengthscale=ell, outputscale=jnp.float32(1.3))
@@ -617,7 +621,7 @@ class TestShardedFused:
                        settings=dataclasses.replace(s, fuse_cg=True))
         params = gp.init_params(X)
         lp1, g1 = jax.value_and_grad(gp.loss)(params, X, y, key)
-        with mesh:
+        with jax.set_mesh(mesh):
             lp8, g8 = jax.value_and_grad(gp.loss)(params, X, y, key)
             lpf, gf = jax.value_and_grad(gp_f.loss)(params, X, y, key)
         np.testing.assert_allclose(float(lp8), float(lp1), rtol=1e-4)

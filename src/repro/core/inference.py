@@ -526,11 +526,12 @@ def _run_engine(
     mirroring y's."""
     n = y.shape[-1]
     batch_shape = y.shape[:-1]
-    precond = build_preconditioner(
-        op, settings.precond_rank, jitter=settings.precond_jitter
-    )
-    Z = precond.sample_probes(key, settings.num_probes, n).astype(y.dtype)
-    Z = jnp.broadcast_to(Z, (*batch_shape, n, settings.num_probes))
+    with jax.named_scope("bbmm.precond"):
+        precond = build_preconditioner(
+            op, settings.precond_rank, jitter=settings.precond_jitter
+        )
+        Z = precond.sample_probes(key, settings.num_probes, n).astype(y.dtype)
+        Z = jnp.broadcast_to(Z, (*batch_shape, n, settings.num_probes))
     B = jnp.concatenate([y[..., None], Z], axis=-1)
 
     matmul, refresh_kwargs, fused_step = _solver_matmuls(op, settings)
@@ -547,15 +548,17 @@ def _run_engine(
     probe_solves = res.solves[..., 1:]
 
     if with_logdet:
-        probe_res = res._replace(
-            solves=probe_solves,
-            tridiag_alpha=res.tridiag_alpha[..., 1:, :],
-            tridiag_beta=res.tridiag_beta[..., 1:, :],
-            active_steps=res.active_steps[..., 1:, :],
-            num_iters=res.num_iters[..., 1:],
-            residual_norm=res.residual_norm[..., 1:],
-        )
-        logdet = logdet_from_mbcg(probe_res, precond.inv_quad(Z), precond.logdet())
+        with jax.named_scope("bbmm.logdet"):
+            probe_res = res._replace(
+                solves=probe_solves,
+                tridiag_alpha=res.tridiag_alpha[..., 1:, :],
+                tridiag_beta=res.tridiag_beta[..., 1:, :],
+                active_steps=res.active_steps[..., 1:, :],
+                num_iters=res.num_iters[..., 1:],
+                residual_norm=res.residual_norm[..., 1:],
+            )
+            probe_quads, precond_logdet = precond.inv_quad(Z), precond.logdet()
+        logdet = logdet_from_mbcg(probe_res, probe_quads, precond_logdet)
     else:
         logdet = jnp.float32(jnp.nan)  # not computed in a mean-only build
     return precond, Z, res, probe_solves, logdet
@@ -567,13 +570,15 @@ def _engine_forward_report(
     """Engine forward pass + its health verdict (None under tracing)."""
     precond, Z, res, probe_solves, logdet = _run_engine(op, y, key, settings)
     u = res.solves[..., 0]
+    with jax.named_scope("bbmm.precond"):
+        precond_probes = precond.solve(Z)
     state = InferenceState(
         solve_y=u,
         inv_quad=jnp.sum(y * u, axis=-1),
         logdet=logdet,
         probe_solves=probe_solves,
         probes=Z,
-        precond_probes=precond.solve(Z),
+        precond_probes=precond_probes,
         cg_iters=res.num_iters,
         residual=res.residual_norm,
     )
@@ -592,8 +597,7 @@ def _engine_forward(
     context: str = "mll",
 ):
     t0 = time.perf_counter()
-    with obs.span("engine_forward", context=context):
-        state, report = _engine_forward_report(op, y, key, settings)
+    state, report = _engine_forward_report(op, y, key, settings)
     # check-only here: this is the differentiable-MLL seam, where a retry
     # would desynchronize the custom-VJP residuals — training's recovery
     # policy lives in fit_gp, serving's in the session layer
@@ -626,26 +630,27 @@ def inv_quad_logdet(
 
     @f32_matmuls  # transposed outside inv_quad_logdet's own call
     def _bwd(residuals, cotangents):
-        op, u, probe_solves, pinv_z, key = residuals
-        g_iq, g_ld = cotangents
-        t = probe_solves.shape[-1]
-        g_iq = jnp.asarray(g_iq)[..., None, None]  # broadcast over (n, t)
-        g_ld = jnp.asarray(g_ld)[..., None, None]
+        with jax.named_scope("bbmm.backward"):
+            op, u, probe_solves, pinv_z, key = residuals
+            g_iq, g_ld = cotangents
+            t = probe_solves.shape[-1]
+            g_iq = jnp.asarray(g_iq)[..., None, None]  # broadcast over (n, t)
+            g_ld = jnp.asarray(g_ld)[..., None, None]
 
-        # One vjp through the blackbox matmul covers both estimators.
-        rhs = jnp.concatenate([u[..., None], probe_solves], axis=-1)
-        rhs = jax.lax.stop_gradient(rhs)
-        cot = jnp.concatenate(
-            [(-g_iq) * u[..., None], (g_ld / t) * pinv_z], axis=-1
-        )
-        cot = cot.astype(rhs.dtype)
+            # One vjp through the blackbox matmul covers both estimators.
+            rhs = jnp.concatenate([u[..., None], probe_solves], axis=-1)
+            rhs = jax.lax.stop_gradient(rhs)
+            cot = jnp.concatenate(
+                [(-g_iq) * u[..., None], (g_ld / t) * pinv_z], axis=-1
+            )
+            cot = cot.astype(rhs.dtype)
 
-        _, matmul_vjp = jax.vjp(lambda o: o.matmul(rhs), op)
-        (d_op,) = matmul_vjp(cot)
+            _, matmul_vjp = jax.vjp(lambda o: o.matmul(rhs), op)
+            (d_op,) = matmul_vjp(cot)
 
-        d_y = 2.0 * g_iq[..., 0] * u
-        d_key = np.zeros(key.shape, dtype=jax.dtypes.float0)
-        return d_op, d_y, d_key
+            d_y = 2.0 * g_iq[..., 0] * u
+            d_key = np.zeros(key.shape, dtype=jax.dtypes.float0)
+            return d_op, d_y, d_key
 
     _iql.defvjp(_fwd, _bwd)
     return _iql(op, y, key)

@@ -111,6 +111,7 @@ period) apply unchanged.
 
 from __future__ import annotations
 
+import functools
 import time
 from functools import partial
 from typing import Callable, NamedTuple
@@ -376,6 +377,23 @@ def _safe_rsqrt(x):
     return jnp.where(ok, jax.lax.rsqrt(jnp.where(ok, x, 1.0)), 0.0)
 
 
+def _named_scope(name: str):
+    """Decorator: trace each call of the function under a fresh
+    ``jax.named_scope(name)``, so every op it stages carries ``name`` in its
+    ``op_name`` metadata (a scope object holds its caller's name stack, so
+    one instance cannot be shared between calls)."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
+
+
 @partial(
     jax.jit,
     static_argnames=(
@@ -390,6 +408,7 @@ def _safe_rsqrt(x):
         "fused_step",
     ),
 )
+@_named_scope("bbmm.mbcg")
 def _mbcg_jit(
     matmul: Callable[[jax.Array], jax.Array],
     B: jax.Array,
@@ -407,7 +426,9 @@ def _mbcg_jit(
     """Solve K̂⁻¹B for all columns (and all leading batch dims) of B at once.
 
     This is the jitted body; :func:`mbcg` is the public entry point (same
-    signature) whose only addition is host-side telemetry.
+    signature) whose only addition is host-side telemetry.  Every op of the
+    solve, all four loop bodies included, is staged under the
+    ``bbmm.mbcg`` name scope, which a device trace reads its ops by.
 
     Args:
       matmul: blackbox ``M ↦ K̂ @ M`` for (..., n, t) M (must broadcast over
@@ -714,10 +735,15 @@ def mbcg(
       curvature skips) is host-read and folded into ``cg_*`` series, plus
       an amortised per-iteration wall time (first call includes compile);
     * **trace() active**: the call is wrapped in an ``"mbcg"`` span;
-    * **called under jit/grad** (results are tracers): everything above
-      no-ops, so the traced program — and its jaxpr — is unchanged.
+    * **called under jit/grad** (``B`` is a tracer): straight into the
+      jitted body as with no sink, so nothing is recorded at trace time and
+      the traced program — and its jaxpr — is unchanged.  Inside a jitted
+      step the solve's iterations and time are read from the device trace
+      (the ``bbmm.mbcg`` scope), not from here.
     """
-    if obs.active() is None and obs.active_trace() is None:
+    if isinstance(B, jax.core.Tracer) or (
+        obs.active() is None and obs.active_trace() is None
+    ):
         return _mbcg_jit(
             matmul,
             B,

@@ -45,7 +45,8 @@ def logdet_from_mbcg(
       probe_inv_quads: (t,) values zᵢᵀP̂⁻¹zᵢ (≡ ‖zᵢ‖² when unpreconditioned).
       precond_logdet: log|P̂| (0 when unpreconditioned).
     """
-    T = tridiag_matrices(result)
-    quad = slq_quadrature(T)  # (..., t)
-    est = jnp.mean(probe_inv_quads * quad, axis=-1)
-    return est + precond_logdet
+    with jax.named_scope("bbmm.logdet"):
+        T = tridiag_matrices(result)
+        quad = slq_quadrature(T)  # (..., t)
+        est = jnp.mean(probe_inv_quads * quad, axis=-1)
+        return est + precond_logdet

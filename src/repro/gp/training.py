@@ -34,6 +34,10 @@ Robustness (the training leg of the solve-health layer):
     ``precision="highest"`` (once; the poisoned update is discarded), and
     ``warn`` records the non-finite loss and skips the poisoned update so
     the parameters never absorb NaN gradients.
+
+Each step runs under two host spans, ``fit:dispatch`` (the jitted step's
+call) and ``fit:sync`` (reading the loss back), which land in any
+``jax.profiler`` capture of a fit beside the device ops they wait for.
 """
 
 from __future__ import annotations
@@ -122,8 +126,10 @@ def fit_gp(
     while i < steps:
         key, sub = jax.random.split(key)
         t_step = time.perf_counter()
-        params_new, opt_new, loss = step(params, opt, sub)
-        loss_f = float(loss)  # host sync — the step is done here
+        with obs.span("fit:dispatch"):
+            params_new, opt_new, loss = step(params, opt, sub)
+        with obs.span("fit:sync"):
+            loss_f = float(loss)  # host sync — the step is done here
         if obs.active() is not None:
             # per-step training telemetry for gp_top during long fits
             mname = type(model).__name__

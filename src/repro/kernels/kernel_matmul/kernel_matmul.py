@@ -99,6 +99,14 @@ from repro.core.precision import as_jnp_dtype, normalize_compute_dtype
 # until the block fits (the X/M/kernel tiles are small next to it).
 _BATCH_OUT_VMEM_BYTES = 4 * 1024 * 1024
 
+# Stable ``pallas_call`` names: each launch's device op is named after its
+# kernel, so a profiler trace finds the kernel-matrix products by the
+# substring "kernel_matmul" and the fused CG steps by "cg_step" alone.
+KERNEL_MATMUL = "kernel_matmul"
+KERNEL_MATMUL_BATCHED = "kernel_matmul_batched"
+FUSED_CG_STEP = "fused_cg_step"
+PANEL_FUSED_CG_STEP = "panel_fused_cg_step"
+
 
 def _mxu_precision(mxu_dtype):
     """Explicit MXU precision: f32 operands get full f32 contraction
@@ -352,6 +360,7 @@ def kernel_matmul_pallas(
             out_specs=pl.BlockSpec((batch, bn, t), lambda i, j, b: (0, i, 0)),
             out_shape=jax.ShapeDtypeStruct((batch, rows, t), jnp.float32),
             interpret=interpret,
+            name=KERNEL_MATMUL_BATCHED,
         )(off, X1, X2, M, scal)
 
     grid = (pl.cdiv(rows, bn), pl.cdiv(cols, bm))
@@ -368,6 +377,7 @@ def kernel_matmul_pallas(
         out_specs=pl.BlockSpec((bn, t), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, t), jnp.float32),
         interpret=interpret,
+        name=KERNEL_MATMUL,
     )(off, X1, X2, M, scal)
 
 
@@ -518,6 +528,7 @@ def fused_cg_step_pallas(
     bm: int = 512,
     interpret: bool = False,
     compute_dtype: str = "float32",
+    name: str = FUSED_CG_STEP,
 ):
     """One fused CG iteration of K̂ = K(X, X) + σ²I: applies the pending
     (α, β, γ) state updates, computes V = K̂·D_new tile-by-tile, and
@@ -584,6 +595,7 @@ def fused_cg_step_pallas(
             jax.ShapeDtypeStruct((batch, 4, t), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(off, X1, X2, R_cols, D_cols, V_cols, U, R, D, V, scal, ab)
 
 

@@ -50,10 +50,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import obs
 from repro.core.precision import as_jnp_dtype, normalize_compute_dtype
 from .kernel_matmul import (
     _FUSED_STATE_SLABS,
+    FUSED_CG_STEP,
+    PANEL_FUSED_CG_STEP,
     fused_cg_step_pallas,
     kernel_matmul_pallas,
 )
@@ -215,19 +216,18 @@ def fused_kernel_matmul(
 ):
     """(K(X,X)+σ²I) @ M via the Pallas kernel (any n — no padding of M)."""
     Xs = prescale_inputs(X, lengthscale, compute_dtype)
-    with obs.annotation("pallas:kernel_matmul"):
-        return fused_kernel_matmul_prescaled(
-            Xs,
-            Xs,
-            M,
-            outputscale,
-            sigma2,
-            kernel_type=kernel_type,
-            bn=bn,
-            bm=bm,
-            interpret=interpret,
-            compute_dtype=compute_dtype,
-        )
+    return fused_kernel_matmul_prescaled(
+        Xs,
+        Xs,
+        M,
+        outputscale,
+        sigma2,
+        kernel_type=kernel_type,
+        bn=bn,
+        bm=bm,
+        interpret=interpret,
+        compute_dtype=compute_dtype,
+    )
 
 
 def _stationary_kernel_type(kernel):
@@ -370,7 +370,7 @@ def _flatten_state(arr, n, t):
 
 @partial(
     jax.jit,
-    static_argnames=("kernel_type", "bn", "bm", "interpret", "compute_dtype"),
+    static_argnames=("kernel_type", "bn", "bm", "interpret", "compute_dtype", "name"),
 )
 def _fused_cg_step_padded(
     Xs_rows,
@@ -394,10 +394,11 @@ def _fused_cg_step_padded(
     bm=512,
     interpret=None,
     compute_dtype="float32",
+    name=FUSED_CG_STEP,
 ):
     """Shared core of the fused CG step wrappers: flatten leading batch dims,
-    lane-pad the probe dim (compiled mode), run the fused kernel, restore
-    shapes.  Padded probe columns are all-zero state with α=β=γ=0, so they
+    lane-pad the probe dim (compiled mode), run the fused kernel (its
+    ``pallas_call`` named ``name``), restore shapes.  Padded probe columns are all-zero state with α=β=γ=0, so they
     contribute zero updates and zero reductions — stripped on return."""
     if interpret is None:
         interpret = not _on_tpu()
@@ -443,6 +444,7 @@ def _fused_cg_step_padded(
         bm=bm,
         interpret=interpret,
         compute_dtype=compute_dtype,
+        name=name,
     )
     out_shape = lead + (rows, t0)
     Un, Rn, Dn, Vn = (a[..., :t0].reshape(out_shape) for a in (Un, Rn, Dn, Vn))
@@ -476,28 +478,27 @@ def fused_cg_step_prescaled(
     state, computes V = K̂·D tile-by-tile and returns the four per-column
     reductions [dᵀV, rᵀr, rᵀV, vᵀV] — ONE kernel launch, no XLA pass over
     the O(n·t) state.  Leading batch dims run on the native batch grid."""
-    with obs.annotation("pallas:fused_cg_step"):
-        return _fused_cg_step_padded(
-            Xs,
-            Xs,
-            U,
-            R,
-            D,
-            V,
-            R,
-            D,
-            V,
-            alpha,
-            beta,
-            gamma,
-            outputscale,
-            sigma2,
-            kernel_type=kernel_type,
-            bn=bn,
-            bm=bm,
-            interpret=interpret,
-            compute_dtype=compute_dtype,
-        )
+    return _fused_cg_step_padded(
+        Xs,
+        Xs,
+        U,
+        R,
+        D,
+        V,
+        R,
+        D,
+        V,
+        alpha,
+        beta,
+        gamma,
+        outputscale,
+        sigma2,
+        kernel_type=kernel_type,
+        bn=bn,
+        bm=bm,
+        interpret=interpret,
+        compute_dtype=compute_dtype,
+    )
 
 
 def _panel_fused_cg_step_bands(
@@ -560,6 +561,7 @@ def _panel_fused_cg_step_bands(
         bm=bm,
         interpret=interpret,
         compute_dtype=compute_dtype,
+        name=PANEL_FUSED_CG_STEP,
     )
     red = tuple(jnp.zeros(lead + (t,), jnp.float32) for _ in range(4))
 
@@ -629,13 +631,12 @@ def panel_fused_cg_step_prescaled(
     the same arrays every panel reads — and the (4, t) reductions are
     carried across the panel loop (see :func:`_panel_fused_cg_step_bands`).
     """
-    with obs.annotation("pallas:panel_fused_cg_step"):
-        return _panel_fused_cg_step_bands(
-            Xs, Xs, U, R, D, V, R, D, V,
-            alpha, beta, gamma, outputscale, sigma2, 0,
-            panel_rows=panel_rows, kernel_type=kernel_type,
-            bn=bn, bm=bm, interpret=interpret, compute_dtype=compute_dtype,
-        )
+    return _panel_fused_cg_step_bands(
+        Xs, Xs, U, R, D, V, R, D, V,
+        alpha, beta, gamma, outputscale, sigma2, 0,
+        panel_rows=panel_rows, kernel_type=kernel_type,
+        bn=bn, bm=bm, interpret=interpret, compute_dtype=compute_dtype,
+    )
 
 
 def sharded_fused_cg_step_prescaled(

@@ -4,9 +4,9 @@ Three pieces, one discipline:
 
 * :mod:`repro.obs.registry` — process-wide metrics registry (counters,
   gauges, fixed-log-bucket histograms; thread-safe, label-keyed).
-* :mod:`repro.obs.trace` — per-solve trace spans emitting Chrome
-  trace-event JSON (Perfetto-loadable), plus optional
-  ``jax.profiler.TraceAnnotation`` pass-through at pallas launch sites.
+* :mod:`repro.obs.trace` — per-solve host spans emitting Chrome
+  trace-event JSON (Perfetto-loadable), each also a
+  ``jax.profiler.TraceAnnotation`` on the device trace's clock.
 * :mod:`repro.obs.exposition` — Prometheus ``/metrics`` + ``/health``
   JSON on a stdlib ``http.server`` daemon thread, and the text-format
   parser behind the ``gp_top`` CLI.
@@ -31,8 +31,6 @@ from .registry import (  # noqa: F401
 from .trace import (  # noqa: F401
     TraceCollector,
     active_trace,
-    annotation,
-    enable_jax_annotations,
     instant,
     span,
     trace,
@@ -46,8 +44,6 @@ __all__ = [
     "TraceCollector",
     "active",
     "active_trace",
-    "annotation",
-    "enable_jax_annotations",
     "inc",
     "install",
     "installed",

@@ -1,10 +1,10 @@
 """Per-solve trace spans → Chrome trace-event JSON (Perfetto-loadable).
 
 A :func:`trace` context installs a process-wide :class:`TraceCollector`;
-instrumented code opens nested :func:`span`s (solve → rung attempt → mbcg
-→ panel launch) and drops :func:`instant` markers.  The collector writes
-the Trace Event Format's "X" (complete) and "i" (instant) events with
-microsecond timestamps, so the file loads directly in Perfetto
+instrumented code opens nested :func:`span`s (solve → rung attempt → mbcg)
+and drops :func:`instant` markers.  The collector writes the Trace Event
+Format's "X" (complete) and "i" (instant) events with microsecond
+timestamps, so the file loads directly in Perfetto
 (https://ui.perfetto.dev) or ``chrome://tracing``:
 
     with obs.trace("solve.trace.json"):
@@ -15,14 +15,18 @@ thread whose [ts, ts+dur] intervals contain one another render as a
 flame-graph stack.  Thread id = Python ``threading.get_ident()`` so the
 serving session's worker threads get their own rows.
 
-Same null-sink discipline as the metrics registry: with no collector
-installed, :func:`span` yields immediately and :func:`instant` is a
-``None``-check.  No jax imports at module scope — the optional
-``jax.profiler.TraceAnnotation`` pass-through (:func:`annotation`, for
-correlating our spans with device-side XLA/pallas activity in a
-``jax.profiler.trace`` capture) imports jax lazily and only when
-explicitly enabled via :func:`enable_jax_annotations` or
-``REPRO_OBS_JAX_TRACE=1``.
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+so inside a ``jax.profiler`` capture the program's host spans sit on the
+device trace's clock, next to the device ops they dispatch and wait for.
+Spans record run time only: a span opened while JAX traces a function
+(under ``jit``, ``grad``, ``vmap``) would time the tracing, once per
+compile, so it records nothing.  What a jitted step does on the device is
+read from the device trace by its named scopes (``bbmm.*``, ``optim.adam``)
+and kernel names, not from this collector.
+
+With no collector installed, :func:`span` only enters the annotation (a
+no-op outside a capture) and :func:`instant` is a ``None``-check.  No jax
+imports at module scope: jax is imported on the first span.
 """
 
 from __future__ import annotations
@@ -129,18 +133,38 @@ def trace(path: Optional[str] = None, *, collector: Optional[TraceCollector] = N
             col.save(path)
 
 
+_jax_hooks = None  # (TraceAnnotation, is_top_level) once jax is imported
+
+
+def _hooks():
+    global _jax_hooks
+    if _jax_hooks is None:
+        import jax
+        from jax.profiler import TraceAnnotation
+
+        _jax_hooks = (TraceAnnotation, jax.core.trace_ctx.is_top_level)
+    return _jax_hooks
+
+
 @contextmanager
 def span(name: str, **args):
-    """A named trace span covering the block; no-op when no trace() active."""
-    col = _active
-    if col is None:
+    """A named host span covering the block: a ``jax.profiler`` annotation,
+    plus a complete event in the active trace() collector if any.  Records
+    nothing while JAX traces a function (see the module docstring)."""
+    annotation, at_top_level = _hooks()
+    if not at_top_level():
         yield None
         return
-    t0 = col.now_us()
-    try:
-        yield col
-    finally:
-        col.add_complete(name, t0, col.now_us() - t0, args or None)
+    with annotation(name):
+        col = _active
+        if col is None:
+            yield None
+            return
+        t0 = col.now_us()
+        try:
+            yield col
+        finally:
+            col.add_complete(name, t0, col.now_us() - t0, args or None)
 
 
 def instant(name: str, **args) -> None:
@@ -148,33 +172,3 @@ def instant(name: str, **args) -> None:
     col = _active
     if col is not None:
         col.add_instant(name, args or None)
-
-
-# --- optional jax.profiler.TraceAnnotation pass-through --------------------
-
-_jax_annotations_enabled = os.environ.get("REPRO_OBS_JAX_TRACE", "") not in ("", "0")
-
-
-def enable_jax_annotations(enabled: bool = True) -> None:
-    """Toggle jax.profiler.TraceAnnotation emission at pallas launch sites.
-
-    Off by default: annotations only matter inside a ``jax.profiler.trace``
-    capture, and importing jax.profiler from library seams unconditionally
-    would violate the zero-overhead discipline."""
-    global _jax_annotations_enabled
-    _jax_annotations_enabled = enabled
-
-
-@contextmanager
-def annotation(name: str):
-    """jax.profiler.TraceAnnotation(name) when enabled, else a no-op."""
-    if not _jax_annotations_enabled:
-        yield
-        return
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # pragma: no cover - jax without profiler
-        yield
-        return
-    with TraceAnnotation(name):
-        yield

@@ -30,6 +30,10 @@ def adam(lr=1e-3, b1=0.9, b2=0.999, eps=1e-8, state_dtype=jnp.float32):
         return AdamState(jnp.zeros((), jnp.int32), mu, nu)
 
     def update(grads, state, params):
+        with jax.named_scope("optim.adam"):
+            return _update(grads, state, params)
+
+    def _update(grads, state, params):
         step = state.step + 1
         stepf = step.astype(jnp.float32)
         lr_t = sched(stepf)
@@ -59,6 +63,10 @@ def adamw(lr=1e-3, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.01, state_dtype=jn
     init, _ = adam(lr, b1, b2, eps, state_dtype)
 
     def update(grads, state, params):
+        with jax.named_scope("optim.adam"):
+            return _update(grads, state, params)
+
+    def _update(grads, state, params):
         step = state.step + 1
         stepf = step.astype(jnp.float32)
         lr_t = sched(stepf)

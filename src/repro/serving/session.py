@@ -578,7 +578,9 @@ class PosteriorSession:
         d0 = self.degraded_queries
         with obs.span("serving:query"):
             try:
-                out = self._query_impl(Xstar, **kwargs)
+                # the latency sample and the span end at ready answers, not
+                # at the enqueue of an asynchronously dispatched computation
+                out = jax.block_until_ready(self._query_impl(Xstar, **kwargs))
             except Exception:
                 obs.inc("serving_queries_total", result="error")
                 raise

@@ -1738,11 +1738,13 @@ class PartitionedKernelOperator(LinearOperator):
         from repro.core.precision import as_jnp_dtype
         from repro.kernels.kernel_matmul.ops import (
             choose_panel_rows,
+            lane_aligned,
             panel_fused_cg_step_prescaled,
             sharded_fused_cg_step_prescaled,
         )
 
         itemsize = jnp.dtype(as_jnp_dtype(op.compute_dtype)).itemsize
+        Xs = None if op.Xs is None else lane_aligned(op.Xs)
         n_band = n // shards
 
         def step(U, R, D, V, alpha, beta, gamma):
@@ -1787,11 +1789,11 @@ class PartitionedKernelOperator(LinearOperator):
                 )
                 if shards > 1:
                     return sharded_fused_cg_step_prescaled(
-                        op.Xs, U, R, D, V, alpha, beta, gamma,
+                        Xs, U, R, D, V, alpha, beta, gamma,
                         op.kernel.outputscale, s2, mesh, op.data_axes, **kw,
                     )
                 return panel_fused_cg_step_prescaled(
-                    op.Xs, U, R, D, V, alpha, beta, gamma,
+                    Xs, U, R, D, V, alpha, beta, gamma,
                     op.kernel.outputscale, s2, **kw,
                 )
             if shards > 1:

@@ -8,14 +8,32 @@ in float32.
 
 Two policies, named from the user-facing end down to the kernel:
 
-  * ``precision="highest"`` → ``compute_dtype="float32"``: every stage f32
-    (the seed behaviour).
+  * ``precision="highest"`` → ``compute_dtype="float32"``: f32 accuracy in
+    every stage.  The TPU's MXU multiplies bf16 only, so f32 accuracy there
+    is a sum of bf16 products: each f32 operand is split exactly into three
+    bf16 pieces, hi + mid + lo.  The kernel-matmul launch
+    (``repro.kernels.kernel_matmul``) carries those pieces side by side in
+    the lanes that padding to 128 would waste and runs each MXU stage as
+    native bf16 passes with f32 accumulation:
+
+      - distances ⟨x, x'⟩: hi·hi + hi·mid + mid·hi + hi·lo + lo·hi +
+        mid·mid — the term set of ``Precision.HIGHEST``'s six passes — in
+        ONE pass over round_up(6d, 128) lanes (d ≤ 21 fits one lane tile;
+        HIGHEST makes six over round_up(d, 128));
+      - the tile×RHS product: all nine cross terms of K's and M's splits,
+        in three passes over round_up(3t, 128) lanes; used while that beats
+        HIGHEST's six over round_up(t, 128), i.e. t ≤ 42, else HIGHEST.
+
+    Dropping mid·lo, lo·mid and lo·lo costs at most ~2⁻²³ of |x||x'| per
+    feature, the size of f32's own rounding: this is f32 arithmetic done
+    on bf16 hardware, not the mixed policy.  The fused CG step keeps
+    ``Precision.HIGHEST`` on f32 operands.
   * ``precision="mixed"``   → ``compute_dtype="bfloat16"``: kernel tiles and
-    the tile×RHS product run in bf16 with f32 accumulation
-    (``preferred_element_type=float32``) — double MXU throughput and half
-    the HBM/all-gather payload for X and M.  CG tolerance semantics are
-    preserved by a periodic f32 residual refresh inside mBCG (see
-    ``repro.core.mbcg``).
+    the tile×RHS product run on ONE bf16 piece each, with f32 accumulation
+    (``preferred_element_type=float32``) — one pass per stage and half the
+    HBM/all-gather payload for X and M, at bf16's 2⁻⁸ operand rounding.
+    CG tolerance semantics are preserved by a periodic f32 residual refresh
+    inside mBCG (see ``repro.core.mbcg``).
 
 On TPU, XLA's default f32 matmul is a single bf16 pass, which would
 quietly break the "everything else stays f32" half of both policies.  The

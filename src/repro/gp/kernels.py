@@ -138,6 +138,8 @@ def _pallas_matmul_bwd(res, ct):
     # a prepared operator's pre-scaled Xs is a pure function of
     # (kernel.lengthscale, X), both already accounted for: zero cotangent
     extra = {"Xs": jnp.zeros_like(op.Xs)} if hasattr(op, "Xs") else {}
+    if getattr(op, "split", None) is not None:  # ... and so are its packed splits
+        extra["split"] = jax.tree_util.tree_map(jnp.zeros_like, op.split)
     return dataclasses.replace(op, kernel=kern_bar, X=X_bar, **extra), M_bar
 
 
@@ -228,11 +230,13 @@ class KernelOperator(LinearOperator):
         return mesh
 
     def prepare(self):
-        """Hoist the lengthscale pre-scaling + lane padding out of the CG
-        loop: returns an operator whose per-iteration matmul consumes the
-        already-scaled X (single-device and sharded pallas modes).  Under a
-        bf16 ``compute_dtype`` the pre-scaled X is *stored* in bf16 — half
-        the HBM footprint / gather payload for the whole solve.
+        """Hoist the lengthscale pre-scaling out of the CG loop: returns an
+        operator whose per-iteration matmul consumes the already-scaled X
+        (single-device and sharded pallas modes).  Under a bf16
+        ``compute_dtype`` the pre-scaled X is *stored* in bf16 — half the
+        HBM footprint / gather payload for the whole solve; under f32,
+        ``mode="pallas"`` also packs X's bf16 splits here, once per solve
+        (``ops.pack_split_operands``).
 
         ``mode="pallas_partitioned"`` prepares into the streaming
         :class:`repro.core.PartitionedKernelOperator` — K is never
@@ -244,22 +248,26 @@ class KernelOperator(LinearOperator):
             return self
         from repro.kernels.kernel_matmul.ops import (
             _stationary_kernel_type,
+            pack_split_operands,
             prescale_inputs,
         )
 
-        cls = (
-            PreparedPallasKernelOperator
-            if self.mode == "pallas"
-            else PreparedShardedPallasKernelOperator
-        )
-        extra = {} if self.mode == "pallas" else {
-            "data_axes": self.data_axes,
-            "mesh": self._mesh(),
-        }
+        Xs = prescale_inputs(self.X, self.kernel.lengthscale, self.compute_dtype)
+        if self.mode == "pallas":
+            cls = PreparedPallasKernelOperator
+            # the f32 launch's packed bf16 splits of X, once per solve; no
+            # gradient flows through them (the custom VJP differentiates
+            # the XLA panel stream from kernel and X)
+            extra = {} if is_reduced(self.compute_dtype) else {
+                "split": pack_split_operands(*[jax.lax.stop_gradient(Xs)] * 2)
+            }
+        else:
+            cls = PreparedShardedPallasKernelOperator
+            extra = {"data_axes": self.data_axes, "mesh": self._mesh()}
         return cls(
             kernel=self.kernel,
             X=self.X,
-            Xs=prescale_inputs(self.X, self.kernel.lengthscale, self.compute_dtype),
+            Xs=Xs,
             kernel_type=_stationary_kernel_type(self.kernel),
             compute_dtype=self.compute_dtype,
             **extra,
@@ -324,14 +332,15 @@ class KernelOperator(LinearOperator):
 @dataclasses.dataclass(frozen=True)
 class PreparedPallasKernelOperator(LinearOperator):
     """KernelOperator(mode='pallas') after ``prepare()``: X is already
-    divided by the (possibly ARD) lengthscale and lane-padded, so the CG
-    loop's per-iteration matmul does no redundant pre-scaling work."""
+    divided by the (possibly ARD) lengthscale, so the CG loop's
+    per-iteration matmul does no redundant pre-scaling work."""
 
     kernel: object  # original kernel (row/diagonal accessors, outputscale)
     X: jax.Array  # (n, d) original inputs (row/diagonal accessors)
-    Xs: jax.Array  # (n, d128) pre-scaled + lane-aligned (stored at compute_dtype)
+    Xs: jax.Array  # (n, d) pre-scaled (stored at compute_dtype)
     kernel_type: str = static_field(default="rbf")
     compute_dtype: str = static_field(default="float32")
+    split: object = None  # ops.SplitOperands of Xs, prepared under "float32"
 
     @property
     def shape(self):
@@ -346,8 +355,16 @@ class PreparedPallasKernelOperator(LinearOperator):
         return _pallas_matmul(self, M)
 
     def _pallas_forward(self, M):
-        from repro.kernels.kernel_matmul.ops import fused_kernel_matmul_prescaled
+        from repro.kernels.kernel_matmul.ops import (
+            fused_kernel_matmul_prescaled,
+            split_kernel_matmul,
+        )
 
+        if self.split is not None and not is_reduced(self.compute_dtype):
+            return split_kernel_matmul(
+                self.split, M, self.kernel.outputscale, jnp.float32(0.0),
+                kernel_type=self.kernel_type,
+            )
         return fused_kernel_matmul_prescaled(
             self.Xs,
             self.Xs,
@@ -371,12 +388,12 @@ class PreparedPallasKernelOperator(LinearOperator):
         """One-launch CG iteration: V = (K+σ²I)·D plus the state updates and
         the dᵀV/rᵀr/rᵀV/vᵀV reductions, all inside the Pallas sweep (see
         ``repro.kernels.kernel_matmul.ops.fused_cg_step_prescaled``)."""
-        from repro.kernels.kernel_matmul.ops import fused_cg_step_prescaled
+        from repro.kernels.kernel_matmul.ops import fused_cg_step_prescaled, lane_aligned
 
         s2 = jnp.float32(0.0) if sigma2 is None else jnp.asarray(sigma2)
         if s2.ndim:
             return None
-        Xs, outputscale = self.Xs, self.kernel.outputscale
+        Xs, outputscale = lane_aligned(self.Xs), self.kernel.outputscale
         kernel_type, compute_dtype = self.kernel_type, self.compute_dtype
 
         def step(U, R, D, V, alpha, beta, gamma):
@@ -403,7 +420,7 @@ class PreparedShardedPallasKernelOperator(LinearOperator):
 
     kernel: object
     X: jax.Array
-    Xs: jax.Array  # (n, d128) pre-scaled + lane-aligned, replicated
+    Xs: jax.Array  # (n, d) pre-scaled, replicated
     kernel_type: str = static_field(default="rbf")
     data_axes: tuple = static_field(default=("data",))
     mesh: object = static_field(default=None)
@@ -440,12 +457,15 @@ class PreparedShardedPallasKernelOperator(LinearOperator):
         """Row-partitioned one-launch CG iteration: each device fuses its row
         band's updates + matmul + partial reductions, psum'd to O(t) — see
         ``ops.sharded_fused_cg_step_prescaled``."""
-        from repro.kernels.kernel_matmul.ops import sharded_fused_cg_step_prescaled
+        from repro.kernels.kernel_matmul.ops import (
+            lane_aligned,
+            sharded_fused_cg_step_prescaled,
+        )
 
         s2 = jnp.float32(0.0) if sigma2 is None else jnp.asarray(sigma2)
         if s2.ndim:
             return None
-        Xs, outputscale = self.Xs, self.kernel.outputscale
+        Xs, outputscale = lane_aligned(self.Xs), self.kernel.outputscale
         kernel_type, compute_dtype = self.kernel_type, self.compute_dtype
         mesh, axes = self.mesh, self.data_axes
 

@@ -14,13 +14,37 @@ revisited across j and accumulated in place (classic Pallas reduction
 pattern).  Distance algebra uses the ‖x‖²+‖x'‖²−2xxᵀ expansion so the MXU
 does the heavy lifting; exp/Matérn polynomials run on the VPU.
 
-Precision policy (``compute_dtype``): with ``"bfloat16"`` the two MXU
-stages — the xxᵀ inner products and the kernel-tile × RHS product — take
-bf16 operands but always accumulate in f32 (``preferred_element_type``),
-doubling MXU throughput and halving the X/M VMEM footprint.  The VPU
-stages (norms, distance assembly, exp/Matérn, the σ² diagonal and all edge
-masking) and the output stay f32 regardless: reduced precision is only
-ever applied where the MXU wins pay for it, never to the accumulator.
+Precision policy (``compute_dtype``).  ``"float32"`` (``precision=
+"highest"``) is f32 accuracy on a bf16-native MXU.  An f32 value is
+exactly hi + mid + lo, three bf16 pieces (``split_bf16``), and the operands
+reach the kernel as those pieces, side by side in the lanes that padding to
+128 would otherwise waste; each MXU stage is then a native bf16 pass
+(``Precision.DEFAULT``) with f32 accumulation:
+
+  * distances — ``ops.pack_split_operands`` packs six d-wide groups,
+    [hi, hi, mid, hi, lo, mid] of the rows against [hi, mid, hi, lo, hi,
+    mid] of the columns, so ONE pass over round_up(6d, 128) lanes sums
+    hi·hi + hi·mid + mid·hi + hi·lo + lo·hi + mid·mid: the term set of the
+    six passes ``Precision.HIGHEST`` makes over round_up(d, 128) lanes.
+    The squared norms come in as f32 (rows, 1) and (1, cols) operands, and
+    the diagonal is set to k(x, x) = outputscale (+ σ²) outright: norms and
+    inner product summed in other orders leave a point an ulp of ‖x‖² from
+    itself.
+  * product — M packed as [M_hi | M_mid | M_lo] in 3t lanes; the kernel
+    splits the f32 (bn, bm) tile into K_hi, K_mid, K_lo and makes three
+    passes against it, and the wrapper sums the three lane groups: all nine
+    cross terms, K·M at f32 accuracy in 3 passes instead of HIGHEST's 6.
+    It pays while 3·⌈3t/128⌉ < 6·⌈t/128⌉, i.e. t ≤ 42; a wider M stays f32
+    and takes the HIGHEST product.
+
+A bf16 operand under ``"float32"`` therefore always carries such a split.
+The fused CG step keeps its f32 operands and the HIGHEST passes.
+``"bfloat16"`` (``precision="mixed"``) is a different result, not a faster
+route to the same one: both stages round their operands to ONE bf16 piece
+and take one pass each, which is why that policy needs mBCG's f32 residual
+refresh.  Either way the VPU stages (norms, distance assembly,
+exp/Matérn, the σ² diagonal and all edge masking) and the accumulator and
+output stay f32.
 
 Batched RHS is a *native grid dimension*, not a vmap: for M of shape
 (b, n, t) the grid is (rows, cols, b) with the batch dim innermost, so
@@ -109,9 +133,11 @@ PANEL_FUSED_CG_STEP = "panel_fused_cg_step"
 
 
 def _mxu_precision(mxu_dtype):
-    """Explicit MXU precision: f32 operands get full f32 contraction
-    (Mosaic's and XLA's default on TPU may take fewer bf16 passes), bf16
-    operands their one native pass whatever the ambient default."""
+    """Explicit MXU precision by operand dtype: f32 operands get HIGHEST
+    (Mosaic's ``contract_precision<fp32>``, six bf16 passes on v5e: the
+    fused CG step and the product for t > 42), bf16 operands one native
+    pass whatever the ambient default (the mixed policy, and the packed
+    bf16 splits of the ``"float32"`` policy)."""
     if mxu_dtype == jnp.float32:
         return jax.lax.Precision.HIGHEST
     return jax.lax.Precision.DEFAULT
@@ -133,20 +159,53 @@ def _apply_stationary(kernel_type: str, d2, outputscale):
     raise ValueError(kernel_type)
 
 
+_BF16_HEAD = -65536  # 0xFFFF0000: sign, exponent and the 7 mantissa bits of a bf16
+
+
+def _bf16_head(x, rounded):
+    """x's leading bf16 piece, as f32: its top 8 significant bits, rounded
+    to nearest even or truncated.  Integer arithmetic on the bits, so no
+    compiler may fold it into a convert round trip."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    if rounded:
+        bits = bits + (0x7FFF + ((bits >> 16) & 1))
+    return jax.lax.bitcast_convert_type(bits & _BF16_HEAD, jnp.float32)
+
+
+def split_bf16(x, *, rounded=True):
+    """(hi, mid, lo) bf16 with hi + mid + lo == x exactly, for f32 ``x``:
+    each residual is exact in f32 and each piece holds at most 8
+    significant bits.  ``rounded`` pieces are the smaller (|mid| ≤ 2⁻⁸|x|,
+    |lo| ≤ 2⁻¹⁶|x|), which matters where a product drops terms; truncated
+    ones cost the VPU three integer ops less per piece."""
+    hi = _bf16_head(x, rounded)
+    r = x - hi
+    mid = _bf16_head(r, rounded)
+    lo = r - mid
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, lo))
+
+
 def _masked_kernel_tile(
-    x1, x2, scal_ref, row_offset, i, j, *, kernel_type, bn, bm, n_cols, mxu_dtype
+    x1, x2, scal_ref, row_offset, i, j, *, kernel_type, bn, bm, n_cols, mxu_dtype,
+    norms=None,
 ):
     """One (bn, bm) kernel tile: distances on the MXU (at ``mxu_dtype`` with
-    f32 accumulation), stationary map + σ² diagonal + edge masking in f32."""
+    f32 accumulation), stationary map + σ² diagonal + edge masking in f32.
+    ``norms`` — the f32 (bn, 1) and (1, bm) squared norms — come with packed
+    bf16 splits, whose lanes do not hold x itself; else the norms are
+    reduced here from x."""
     outputscale = scal_ref[0]
     sigma2 = scal_ref[1]
 
     # ‖xi−xj‖² = ‖xi‖² + ‖xj‖² − 2⟨xi, xj⟩   (inner product on the MXU).
     # Norms are a cheap VPU reduction — keep them f32 even in mixed mode.
-    x1f = x1.astype(jnp.float32)
-    x2f = x2.astype(jnp.float32)
-    n1 = jnp.sum(x1f * x1f, axis=-1, keepdims=True)  # (bn, 1)
-    n2 = jnp.sum(x2f * x2f, axis=-1, keepdims=True)  # (bm, 1)
+    if norms is None:
+        x1f = x1.astype(jnp.float32)
+        x2f = x2.astype(jnp.float32)
+        n1 = jnp.sum(x1f * x1f, axis=-1, keepdims=True)  # (bn, 1)
+        n2 = jnp.sum(x2f * x2f, axis=-1, keepdims=True).T  # (1, bm)
+    else:
+        n1, n2 = norms
     inner = jax.lax.dot_general(
         x1.astype(mxu_dtype),
         x2.astype(mxu_dtype),
@@ -154,7 +213,7 @@ def _masked_kernel_tile(
         precision=_mxu_precision(mxu_dtype),
         preferred_element_type=jnp.float32,
     )
-    d2 = jnp.maximum(n1 + n2.T - 2.0 * inner, 0.0)
+    d2 = jnp.maximum(n1 + n2 - 2.0 * inner, 0.0)
 
     k_tile = _apply_stationary(kernel_type, d2, outputscale)
 
@@ -165,45 +224,76 @@ def _masked_kernel_tile(
     # added diagonal σ²I where global row == global col, then edge masking:
     # kernel-tile columns beyond n_cols are zeroed (kills any unspecified
     # values a partial x2 block may have produced — NaN-safe via where)
-    k_tile = k_tile + jnp.where(rows == cols, sigma2, 0.0)
+    if norms is None:
+        k_tile = k_tile + jnp.where(rows == cols, sigma2, 0.0)
+    else:
+        # the packed inner product and the given norms are summed in other
+        # orders, so a point's distance to itself cancels to an ulp of ‖x‖²
+        # (not to 0), which Matérn-1/2's sqrt lifts to ~1e-3: the diagonal
+        # of K(X, X) is known, k(x, x) = outputscale, so it is set
+        k_tile = jnp.where(rows == cols, outputscale + sigma2, k_tile)
     return jnp.where(cols < n_cols, k_tile, 0.0)
 
 
 def _tile_rhs_product(k_tile, m, j, bm, n_cols, mxu_dtype):
-    """Edge-mask the RHS block and run the tile×RHS MXU stage (f32 accum)."""
+    """Edge-mask the RHS block and run the tile×RHS MXU stage (f32 accum).
+    A bf16 ``m`` under the f32 policy is the packed [M_hi | M_mid | M_lo]:
+    the tile goes in as its three bf16 pieces, one pass each."""
     m_rows = j * bm + jax.lax.broadcasted_iota(jnp.int32, m.shape, 0)
-    m = jnp.where(m_rows < n_cols, m, 0.0)
+    masked = jnp.where(m_rows < n_cols, m.astype(jnp.float32), 0.0)
+    if mxu_dtype == jnp.float32 and m.dtype == jnp.bfloat16:
+        # the masked split is exact in bf16; its pieces' products are all
+        # kept, so truncation loses nothing and spares the VPU the rounding
+        m = masked.astype(jnp.bfloat16)
+        hi, mid, lo = (
+            jax.lax.dot_general(
+                piece, m, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32,
+            )
+            for piece in split_bf16(k_tile, rounded=False)
+        )
+        return (lo + mid) + hi
     return jax.lax.dot_general(
         k_tile.astype(mxu_dtype),
-        m.astype(mxu_dtype),
+        masked.astype(mxu_dtype),
         (((1,), (0,)), ((), ())),
         precision=_mxu_precision(mxu_dtype),
         preferred_element_type=jnp.float32,
     )
 
 
+def _tile_partial(x1_ref, x2_ref, m, scal_ref, off_ref, norm_refs, i, j, *,
+                  kernel_type, bn, bm, n_cols, mxu_dtype):
+    """One grid step's (bn, t) partial product.  With ``norm_refs`` the X
+    blocks are packed bf16 splits: their distance stage is one bf16 pass."""
+    norms = tuple(r[...] for r in norm_refs) if norm_refs else None
+    k_tile = _masked_kernel_tile(
+        x1_ref[...], x2_ref[...], scal_ref, off_ref[0], i, j,
+        kernel_type=kernel_type, bn=bn, bm=bm, n_cols=n_cols,
+        mxu_dtype=jnp.bfloat16 if norms else mxu_dtype, norms=norms,
+    )
+    return _tile_rhs_product(k_tile, m, j, bm, n_cols, mxu_dtype)
+
+
 def _kernel_matmul_kernel(
     off_ref,  # (1,) int32  global row offset of the X1 shard (SMEM-like)
-    x1_ref,  # (bn, d)   row block of X / ℓ
-    x2_ref,  # (bm, d)   col block of X / ℓ
+    x1_ref,  # (bn, d)   row block of X / ℓ (or its packed split)
+    x2_ref,  # (bm, d)   col block of X / ℓ (or its packed split)
     m_ref,  # (bm, t)   block of M
     scal_ref,  # (2,)    [outputscale, sigma2]
-    o_ref,  # (bn, t)   output tile (revisited over j)
-    *,
+    *refs,  # [(bn, 1) row norms, (1, bm) col norms,] (bn, t) output tile
     kernel_type: str,
     bn: int,
     bm: int,
     n_cols: int,
     mxu_dtype,
 ):
+    *norm_refs, o_ref = refs
     i, j = pl.program_id(0), pl.program_id(1)
-
-    k_tile = _masked_kernel_tile(
-        x1_ref[...], x2_ref[...], scal_ref, off_ref[0], i, j,
+    partial_out = _tile_partial(
+        x1_ref, x2_ref, m_ref[...], scal_ref, off_ref, norm_refs, i, j,
         kernel_type=kernel_type, bn=bn, bm=bm, n_cols=n_cols, mxu_dtype=mxu_dtype,
-    )
-    partial_out = _tile_rhs_product(
-        k_tile, m_ref[...].astype(jnp.float32), j, bm, n_cols, mxu_dtype
     )
 
     @pl.when(j == 0)
@@ -221,8 +311,7 @@ def _kernel_matmul_batched_kernel(
     x2_ref,  # (bm, d)   col block — shared across the batch grid dim
     m_ref,  # (1, bm, t) block of this batch element's M
     scal_ref,  # (2,)
-    o_ref,  # (b, bn, t) full-batch output slab (revisited over j and b)
-    *,
+    *refs,  # [(bn, 1), (1, bm) norms,] (b, bn, t) output slab (revisited over j and b)
     kernel_type: str,
     bn: int,
     bm: int,
@@ -240,14 +329,11 @@ def _kernel_matmul_batched_kernel(
     indexed only by i, so the (j, b) reduction revisits it on consecutive
     grid steps — the supported Pallas accumulation pattern.
     """
+    *norm_refs, o_ref = refs
     i, j, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    k_tile = _masked_kernel_tile(
-        x1_ref[...], x2_ref[...], scal_ref, off_ref[0], i, j,
+    partial_out = _tile_partial(
+        x1_ref, x2_ref, m_ref[0], scal_ref, off_ref, norm_refs, i, j,
         kernel_type=kernel_type, bn=bn, bm=bm, n_cols=n_cols, mxu_dtype=mxu_dtype,
-    )
-    partial_out = _tile_rhs_product(
-        k_tile, m_ref[0].astype(jnp.float32), j, bm, n_cols, mxu_dtype
     )
 
     sl = pl.dslice(b, 1)
@@ -327,11 +413,18 @@ def kernel_matmul_pallas(
     bm: int = 512,
     interpret: bool = False,
     compute_dtype: str = "float32",
+    norms: tuple[jax.Array, jax.Array] | None = None,
 ) -> jax.Array:
     """(K(X1, X2) + σ²I_global) @ M → (rows, t) or (b, rows, t), edge-masked
     in kernel.  ``compute_dtype="bfloat16"`` runs the MXU stages in bf16 with
     f32 accumulation; the output is always f32.  A 3-dim M takes the native
-    batch grid (one pallas_call, X tiles shared across the batch)."""
+    batch grid (one pallas_call, X tiles shared across the batch).
+
+    ``norms`` = (‖X1‖² as (rows, 1), ‖X2‖² as (1, cols)), f32, marks X1 and
+    X2 as the packed bf16 splits of the distance stage; a bf16 M under
+    ``"float32"`` is the packed split of the product (module docstring).
+    The output then holds one lane group per piece of M, for the caller to
+    sum."""
     batched = M.ndim == 3
     rows, d = X1.shape
     cols, t = M.shape[-2:]
@@ -343,10 +436,15 @@ def kernel_matmul_pallas(
 
     scal = jnp.stack([outputscale.astype(jnp.float32), sigma2.astype(jnp.float32)])
     off = jnp.asarray(row_offset, jnp.int32).reshape(1)
+    norm_args = () if norms is None else tuple(jnp.asarray(v, jnp.float32) for v in norms)
 
     common = dict(kernel_type=kernel_type, bn=bn, bm=bm, n_cols=cols, mxu_dtype=mxu_dtype)
     if batched:
         grid = (pl.cdiv(rows, bn), pl.cdiv(cols, bm), batch)
+        norm_specs = [
+            pl.BlockSpec((bn, 1), lambda i, j, b: (i, 0)),
+            pl.BlockSpec((1, bm), lambda i, j, b: (0, j)),
+        ]
         return pl.pallas_call(
             functools.partial(_kernel_matmul_batched_kernel, **common),
             grid=grid,
@@ -356,14 +454,18 @@ def kernel_matmul_pallas(
                 pl.BlockSpec((bm, d), lambda i, j, b: (j, 0)),
                 pl.BlockSpec((1, bm, t), lambda i, j, b: (b, j, 0)),
                 pl.BlockSpec((2,), lambda i, j, b: (0,)),
-            ],
+            ] + norm_specs[: len(norm_args)],
             out_specs=pl.BlockSpec((batch, bn, t), lambda i, j, b: (0, i, 0)),
             out_shape=jax.ShapeDtypeStruct((batch, rows, t), jnp.float32),
             interpret=interpret,
             name=KERNEL_MATMUL_BATCHED,
-        )(off, X1, X2, M, scal)
+        )(off, X1, X2, M, scal, *norm_args)
 
     grid = (pl.cdiv(rows, bn), pl.cdiv(cols, bm))
+    norm_specs = [
+        pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
+        pl.BlockSpec((1, bm), lambda i, j: (0, j)),
+    ]
     return pl.pallas_call(
         functools.partial(_kernel_matmul_kernel, **common),
         grid=grid,
@@ -373,12 +475,12 @@ def kernel_matmul_pallas(
             pl.BlockSpec((bm, d), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, t), lambda i, j: (j, 0)),
             pl.BlockSpec((2,), lambda i, j: (0,)),
-        ],
+        ] + norm_specs[: len(norm_args)],
         out_specs=pl.BlockSpec((bn, t), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, t), jnp.float32),
         interpret=interpret,
         name=KERNEL_MATMUL,
-    )(off, X1, X2, M, scal)
+    )(off, X1, X2, M, scal, *norm_args)
 
 
 # ---------------------------------------------------------------------------

@@ -3,11 +3,14 @@
 Three layers:
 
   * :func:`prescale_inputs` — the once-per-solve work: ARD lengthscale
-    division + MXU lane alignment of the feature dim.  Hoisted out of the CG
-    loop via ``KernelOperator.prepare()`` so it is paid once per solve, not
-    once per iteration.
+    division.  Hoisted out of the CG loop via ``KernelOperator.prepare()``
+    so it is paid once per solve, not once per iteration.  The feature dim
+    keeps its true width d: the launch lays it into the MXU's lanes.
   * :func:`fused_kernel_matmul` / :func:`fused_kernel_matmul_prescaled` —
-    single-device entry points (edge masking is in-kernel; M is never padded).
+    single-device entry points (edge masking is in-kernel; M is never padded
+    along its rows).  Under ``compute_dtype="float32"`` they pack the bf16
+    splits of X and M into the lanes (scope ``mxu.split_bf16``; see
+    ``kernel_matmul``'s module docstring).
   * :func:`sharded_kernel_matmul` — ``shard_map`` row-partitioned execution:
     each of D devices keeps only its (n/D × bm) kernel tiles in VMEM and the
     only collective per matmul is ONE all-gather of the (n, t) RHS —
@@ -45,6 +48,7 @@ moves the half-width payload when mixed.
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +61,15 @@ from .kernel_matmul import (
     PANEL_FUSED_CG_STEP,
     fused_cg_step_pallas,
     kernel_matmul_pallas,
+    split_bf16,
 )
+
+#: ``jax.named_scope`` around the packed f32 path of one kernel-matrix
+#: product (the packing, the launch and the lane-group sum): a device trace
+#: tells from it which ``kernel_matmul`` events ran on bf16 splits.
+SPLIT_SCOPE = "mxu.split_bf16"
+
+LANES = 128
 
 
 def _pad_to(x, mult, axis):
@@ -74,15 +86,104 @@ def _on_tpu():
 
 
 def prescale_inputs(X, lengthscale, compute_dtype="float32"):
-    """X/ℓ (ARD broadcasts a (d,) ℓ per-dimension) + lane-align features.
+    """X/ℓ (ARD broadcasts a (d,) ℓ per-dimension), (n, d).
 
     This is everything about X the kernel needs that does not change across
     CG iterations — call once per solve.  The result is stored at
     ``compute_dtype``: under the mixed policy X lives in bf16 from here on,
     halving its HBM footprint and (sharded) broadcast payload; the division
-    itself always runs in the input precision first."""
-    Xs = (X / lengthscale).astype(as_jnp_dtype(compute_dtype))
-    return _pad_to(Xs, 128, 1)
+    itself always runs in the input precision first.  It keeps its true d,
+    which the packed f32 path needs; each launch pads its own operands to
+    the 128 lanes."""
+    return (X / lengthscale).astype(as_jnp_dtype(compute_dtype))
+
+
+def lane_aligned(Xs):
+    """Pre-scaled X with its features zero-padded to the 128 lanes that the
+    single-piece launches (the mixed policy, the fused CG step) contract
+    over; a no-op when aligned, so the fused-step factories pad once per
+    solve."""
+    return _pad_to(Xs, LANES, 1)
+
+
+def split_product_pays(t):
+    """Whether the packed product, three bf16 passes over ⌈3t/128⌉ lane
+    tiles, takes fewer passes than HIGHEST's six over ⌈t/128⌉: t ≤ 42."""
+    return 3 * -(-3 * t // LANES) < 6 * -(-t // LANES)
+
+
+class SplitOperands(NamedTuple):
+    """X's side of the packed f32 launch (:func:`pack_split_operands`)."""
+
+    rows: jax.Array  # (rows, round_up(6d, 128)) bf16 [hi, hi, mid, hi, lo, mid]
+    cols: jax.Array  # (cols, round_up(6d, 128)) bf16 [hi, mid, hi, lo, hi, mid]
+    row_norms: jax.Array  # (rows, 1) f32 ‖x‖²
+    col_norms: jax.Array  # (1, cols) f32 ‖x‖²
+
+
+def pack_split_operands(Xs_rows, Xs_cols) -> SplitOperands:
+    """The distance stage's packed bf16 operands and f32 squared norms.
+
+    Six d-wide groups per row, [hi, hi, mid, hi, lo, mid] of the rows
+    against [hi, mid, hi, lo, hi, mid] of the columns, lane-padded to
+    round_up(6d, 128): one bf16 pass returns the six-term sum of
+    ``Precision.HIGHEST``.  A pure function of the pre-scaled X: a solve
+    packs once (``PreparedPallasKernelOperator``), a bare call per call."""
+    with jax.named_scope(SPLIT_SCOPE):
+        X1 = Xs_rows.astype(jnp.float32)
+        X2 = Xs_cols.astype(jnp.float32)
+        h1, m1, l1 = split_bf16(X1)
+        h2, m2, l2 = split_bf16(X2)
+        return SplitOperands(
+            _pad_to(jnp.concatenate([h1, h1, m1, h1, l1, m1], axis=1), LANES, 1),
+            _pad_to(jnp.concatenate([h2, m2, h2, l2, h2, m2], axis=1), LANES, 1),
+            jnp.sum(X1 * X1, axis=1, keepdims=True),
+            jnp.sum(X2 * X2, axis=1)[None, :],
+        )
+
+
+@partial(jax.jit, static_argnames=("kernel_type", "bn", "bm", "interpret"))
+def split_kernel_matmul(
+    packed: SplitOperands,
+    M,
+    outputscale,
+    sigma2,
+    row_offset=0,
+    *,
+    kernel_type="rbf",
+    bn=256,
+    bm=512,
+    interpret=None,
+):
+    """(K(X1,X2)+σ²I) @ M under ``compute_dtype="float32"``, from X's packed
+    splits: M's own split where :func:`split_product_pays` (else f32 and a
+    HIGHEST product), ONE ``kernel_matmul`` launch, the lane groups summed.
+    Returns f32 (…, rows, t) like :func:`fused_kernel_matmul_prescaled`."""
+    if interpret is None:
+        interpret = not _on_tpu()
+    with jax.named_scope(SPLIT_SCOPE):
+        squeeze = M.ndim == 1
+        M = (M[:, None] if squeeze else M).astype(jnp.float32)
+        t = M.shape[-1]
+        split_rhs = split_product_pays(t)
+        if split_rhs:
+            M = jnp.concatenate(split_bf16(M, rounded=False), axis=-1)
+        if not interpret:
+            # compiled (Mosaic) path: keep the tile's trailing dim a multiple
+            # of the 128-lane MXU — the row dim needs no padding (masked)
+            M = _pad_to(M, LANES, M.ndim - 1)
+        out = kernel_matmul_pallas(
+            packed.rows, packed.cols, M, jnp.asarray(outputscale),
+            jnp.asarray(sigma2), row_offset,
+            norms=(packed.row_norms, packed.col_norms),
+            kernel_type=kernel_type, bn=bn, bm=bm, interpret=interpret,
+        )
+        if split_rhs:
+            hi, mid, lo = (out[..., k * t : (k + 1) * t] for k in range(3))
+            out = (lo + mid) + hi
+        else:
+            out = out[..., :t]
+        return out[..., 0] if squeeze else out
 
 
 #: Default working-set budget for one streamed row-panel of K (bytes).
@@ -168,13 +269,21 @@ def fused_kernel_matmul_prescaled(
     already resident in VMEM (b× fewer X-tile loads than the vmapped
     formulation; see ``kernel_matmul.tile_load_counts``).
 
-    M is cast to ``compute_dtype`` per the precision policy — the one
-    deliberate dtype decision of this entry point (f64 callers get the
+    M is brought to ``compute_dtype`` per the precision policy — the one
+    deliberate dtype decision of this entry point: under ``"float32"`` X and
+    M go in as packed bf16 splits of their f32 values (f64 callers get the
     documented f32-accumulate semantics, bf16 callers under the 'highest'
-    policy get the full-precision MXU path)."""
+    policy the full-precision MXU path), under ``"bfloat16"`` as one bf16
+    piece each."""
     if interpret is None:
         interpret = not _on_tpu()
     compute_dtype = normalize_compute_dtype(compute_dtype)
+    kw = dict(kernel_type=kernel_type, bn=bn, bm=bm, interpret=interpret)
+    if compute_dtype == "float32":
+        return split_kernel_matmul(
+            pack_split_operands(Xs_rows, Xs_cols), M, outputscale, sigma2,
+            row_offset, **kw,
+        )
     squeeze = M.ndim == 1
     if squeeze:
         M = M[:, None]
@@ -182,20 +291,11 @@ def fused_kernel_matmul_prescaled(
     if not interpret:
         # compiled (Mosaic) path: keep the tile's trailing dim a multiple of
         # the 128-lane MXU — the row dim needs no padding (in-kernel masked)
-        M = _pad_to(M, 128, M.ndim - 1)
+        M = _pad_to(M, LANES, M.ndim - 1)
     M = M.astype(as_jnp_dtype(compute_dtype))
     out = kernel_matmul_pallas(
-        Xs_rows,
-        Xs_cols,
-        M,
-        jnp.asarray(outputscale),
-        jnp.asarray(sigma2),
-        row_offset,
-        kernel_type=kernel_type,
-        bn=bn,
-        bm=bm,
-        interpret=interpret,
-        compute_dtype=compute_dtype,
+        lane_aligned(Xs_rows), lane_aligned(Xs_cols), M, jnp.asarray(outputscale),
+        jnp.asarray(sigma2), row_offset, compute_dtype=compute_dtype, **kw,
     )
     out = out[..., :t0]
     return out[..., 0] if squeeze else out
@@ -397,7 +497,8 @@ def _fused_cg_step_padded(
     name=FUSED_CG_STEP,
 ):
     """Shared core of the fused CG step wrappers: flatten leading batch dims,
-    lane-pad the probe dim (compiled mode), run the fused kernel (its
+    lane-pad the features and (compiled mode) the probe dim, run the fused
+    kernel (its
     ``pallas_call`` named ``name``), restore shapes.  Padded probe columns are all-zero state with α=β=γ=0, so they
     contribute zero updates and zero reductions — stripped on return."""
     if interpret is None:
@@ -414,6 +515,7 @@ def _fused_cg_step_padded(
     D_cols, _ = _flatten_state(D_cols, cols, t0)
     V_cols, _ = _flatten_state(V_cols, cols, t0)
     b = U.shape[0]
+    Xs_rows, Xs_cols = lane_aligned(Xs_rows), lane_aligned(Xs_cols)
     scalars = [
         jnp.asarray(s, jnp.float32).reshape((b, t0) if lead else (1, t0))
         for s in (alpha, beta, gamma)

@@ -9,13 +9,13 @@ def kernel_matmul_ref(
     """(K(X,X) + σ²I) @ M, materialized — the correctness reference.
 
     ``rows`` (an index array) keeps only those output rows, materializing
-    the (len(rows), n) block of K instead of all of it."""
+    the (len(rows), n) block of K instead of all of it.  Distances are sums
+    of squared differences: no ‖x‖² + ‖x'‖² − 2⟨x, x'⟩ cancellation, whose
+    ulp of ‖x‖² on the diagonal Matérn-1/2's sqrt would lift to ~1e-3."""
     Xs = X / lengthscale
     r = jnp.arange(X.shape[0]) if rows is None else jnp.asarray(rows)
     Xr = Xs[r]
-    n1 = jnp.sum(Xr * Xr, -1)
-    n2 = jnp.sum(Xs * Xs, -1)
-    d2 = jnp.maximum(n1[:, None] + n2[None, :] - 2.0 * (Xr @ Xs.T), 0.0)
+    d2 = jnp.sum(jnp.square(Xr[:, None, :] - Xs[None, :, :]), -1)
     if kernel_type == "rbf":
         K = outputscale * jnp.exp(-0.5 * d2)
     else:

@@ -3,7 +3,8 @@
 The TPU compiler is installed even where no chip is attached: it compiles
 for a ``v5e:2x2`` topology that is described, not present.  Each test
 compiles at the real size of the Protein configuration (n=45 730, d=9
-lane-padded to 128, t=11 probes+y lane-padded to 128) with
+lane-padded to 128, t=11 probes+y lane-padded to 128), or of the Elevators
+one for the packed ``precision="highest"`` launch (n=16 599, d=18), with
 ``interpret=False`` and asserts that the Mosaic kernel
 (``tpu_custom_call``) is in the compiled program.  This catches what the
 interpret-mode tests cannot: unaligned slices, VMEM overruns, programs
@@ -93,6 +94,51 @@ def test_kernel_matmul_compiles(one_chip, compute_dtype, batch):
         )
 
     _compile_text(f, Xs, M, one_chip(()), one_chip(()))
+
+
+def _kernel_dots(jaxpr):
+    """dtypes of the MXU operands of every dot in every Pallas kernel body."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(tuple(v.aval.dtype for v in eqn.invars))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out += _kernel_dots(inner)
+    return out
+
+
+@pytest.mark.parametrize(
+    "batch,t",
+    [(None, T), (2, T), (None, 64)],
+    ids=["elevators", "elevators-batched", "wide-rhs"],
+)
+def test_split_kernel_matmul_compiles(one_chip, batch, t):
+    """The ``precision="highest"`` launch on packed bf16 splits, at the
+    Elevators size (n=16 599, d=18: six groups in 128 lanes; t=11: three in
+    128), with the packing and the lane-group sum around it: the Mosaic
+    kernel is there and its MXU operands are bf16 — all of them where the
+    product is packed, the distances' where t=64 keeps the f32 HIGHEST
+    product."""
+    from repro.kernels.kernel_matmul.ops import fused_kernel_matmul_prescaled
+
+    n, d = 16_599, 18
+    Xs = one_chip((n, d))
+    M = one_chip((n, t) if batch is None else (batch, n, t))
+
+    def f(Xs, M, outputscale, sigma2):
+        return fused_kernel_matmul_prescaled(
+            Xs, Xs, M, outputscale, sigma2, kernel_type="matern52", interpret=False
+        )
+
+    args = (Xs, M, one_chip(()), one_chip(()))
+    _compile_text(f, *args)
+    dots = _kernel_dots(jax.make_jaxpr(f)(*args).jaxpr)
+    bf16, f32 = jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)
+    want = [(bf16, bf16)] * 4 if t <= 42 else [(bf16, bf16), (f32, f32)]
+    assert dots == want, dots
 
 
 def _cg_state(sds, n):

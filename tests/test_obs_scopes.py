@@ -98,9 +98,18 @@ def test_backward_and_its_panel_stream_carry_the_scope(op_names):
                    for n in op_names if n.startswith("jit(step)/"))
 
 
-@pytest.mark.parametrize("scope", ["bbmm.precond", "bbmm.logdet", "optim.adam"])
+@pytest.mark.parametrize(
+    "scope", ["bbmm.precond", "bbmm.logdet", "optim.adam", "mxu.split_bf16"]
+)
 def test_phase_scopes_present(op_names, scope):
     assert any(scope in n for n in op_names)
+
+
+def test_split_scope_holds_the_kernel_product(op_names):
+    """precision="highest": every kernel-matrix product of the solve runs
+    on packed bf16 splits, inside ``mxu.split_bf16``."""
+    kernel = [n for n in op_names if "/kernel_matmul/" in n]
+    assert kernel and all("/mxu.split_bf16/" in n for n in kernel)
 
 
 def test_adam_update_is_scoped_for_both_optimizers():

@@ -34,7 +34,7 @@ from .exact import ExactGP
 from .sgpr import SGPR
 from .ski import SKI, Grid
 from .blr import BayesianLinearRegression
-from .dkl import DKLExactGP, mlp_init, mlp_apply
+from .dkl import DKLExactGP, FeatureMap, mlp_init, mlp_apply, scale_to_bounds
 from .multitask import (
     MultitaskGP,
     MultitaskData,

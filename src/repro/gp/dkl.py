@@ -1,10 +1,18 @@
-"""Deep kernel learning head (paper's SKI+DKL experiments, Wilson 2016).
+"""Deep kernel learning (Wilson et al. 2016; the paper's SKI+DKL experiments).
 
-``DKLExactGP`` puts an RBF/Matérn GP on top of a learned feature map; the
-feature map can be a small MLP (built here) or *any* backbone from the
-repro.models zoo (wrap its pooled hidden state — see
-examples/deep_kernel_lm.py).  Gradients flow into network weights through
-BBMM's custom VJP: the network is just another kernel hyperparameter.
+Two ways to put a GP on a learned feature map:
+
+  * ``SKI(features=FeatureMap(...))`` (``gp/ski.py``) — GPyTorch's DKL
+    example: a ReLU MLP (``FeatureMap``, published widths 18-1000-500-50-2),
+    ``scale_to_bounds`` onto the grid's box, then KISS-GP interpolation on a
+    static grid, W rebuilt from the features inside every traced loss so the
+    MLL gradient reaches the network.
+  * ``DKLExactGP`` — an RBF/Matérn GP on the features through a dense
+    kernel operator; the feature map can be a small MLP (built here) or
+    *any* backbone from the repro.models zoo (wrap its pooled hidden state —
+    see examples/deep_kernel_lm.py).  Gradients flow into network weights
+    through BBMM's custom VJP: the network is just another kernel
+    hyperparameter.
 
 Because the feature map lives *inside* the kernel, DKL reduces to the
 exact-GP serving story on featurized inputs: the full
@@ -36,13 +44,42 @@ def mlp_init(key, sizes):
     return params
 
 
-def mlp_apply(params, X):
+def mlp_apply(params, X, act=jax.nn.tanh):
     h = X
     for i, layer in enumerate(params):
         h = h @ layer["w"] + layer["b"]
         if i < len(params) - 1:
-            h = jax.nn.tanh(h)
+            h = act(h)
     return h
+
+
+def scale_to_bounds(z, lo, hi, bounds):
+    """GPyTorch's ``ScaleToBounds``: one global affine map taking [lo, hi]
+    (the training features' min and max, gradients flowing through both)
+    onto 0.95 of ``bounds``, so every point keeps a cubic stencil inside
+    the grid."""
+    lower, upper = bounds
+    return (z - lo) * (0.95 * (upper - lower) / (hi - lo)) + 0.95 * lower
+
+
+@dataclasses.dataclass(frozen=True)
+class FeatureMap:
+    """The DKL feature extractor SKI interpolates from: a ReLU MLP of
+    ``widths`` (the last is the grid's dimension), its output scaled onto
+    ``bounds``."""
+
+    widths: tuple = (1000, 500, 50, 2)
+    bounds: tuple = (-1.0, 1.0)
+
+    @property
+    def dims(self) -> int:
+        return self.widths[-1]
+
+    def init(self, key, d: int):
+        return mlp_init(key, (d, *self.widths))
+
+    def __call__(self, net, X):
+        return mlp_apply(net, X, jax.nn.relu)
 
 
 @dataclasses.dataclass

@@ -1,8 +1,8 @@
-"""SKI / KISS-GP through BBMM (paper §5).
+"""SKI / KISS-GP through BBMM (paper §5), with an optional learned feature map.
 
 K̂ ≈ W K_UU Wᵀ + σ²I with
   * W — sparse cubic-convolution interpolation weights (4 taps per dim,
-    Keys 1981), precomputed from the data/grid geometry,
+    Keys 1981),
   * K_UU — kernel on a regular grid: a (Kronecker product of) symmetric
     Toeplitz matrices, multiplied via FFT circulant embedding in
     O(m log m) per column.
@@ -10,12 +10,23 @@ K̂ ≈ W K_UU Wᵀ + σ²I with
 Total blackbox-matmul cost: O(t·n·4^d + t·m log m) — the paper's headline
 SKI complexity.  Multi-dimensional grids use the separable (product-kernel)
 form of the RBF kernel, the standard KISS-GP construction.
+
+On raw inputs the grid is fitted to the data and W computed once, in
+``prepare_inputs``.  With ``features=FeatureMap(...)`` (deep kernel
+learning, GPyTorch's DKL example: feature net → ScaleToBounds → grid
+interpolation → Kronecker-Toeplitz K_UU) the grid is static over the
+feature map's bounds and W is rebuilt from the features inside every
+traced ``operator()``: the interpolation weights are differentiable in the
+features, so the MLL gradient reaches the network.  The device ops carry
+the named scopes ``dkl.features`` (network and ScaleToBounds),
+``ski.interp`` (building W, Wᵀ·M, W·V) and ``ski.grid`` (the
+Kronecker-Toeplitz product).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +44,7 @@ from repro.core import (
     marginal_log_likelihood,
     solve as bbmm_solve,
 )
+from .dkl import FeatureMap, scale_to_bounds
 from .exact import _softplus, _inv_softplus
 from .training import fit_gp
 
@@ -71,11 +83,25 @@ class Grid:
         steps = (maxs - mins) / (jnp.array([s - 1 - 2 * pad for s in sizes]))
         return Grid(mins - pad * steps, steps, tuple(sizes))
 
+    @staticmethod
+    def over_bounds(bounds, sizes):
+        """A static grid over ``bounds`` in every dimension, extended past
+        each end as GPyTorch's ``create_grid`` does: ``size`` points from
+        lo − h to hi + h, h = (hi − lo) / (size − 2)."""
+        lo, hi = bounds
+        h = [(hi - lo) / (s - 2) for s in sizes]
+        mins = jnp.asarray([lo - hd for hd in h], jnp.float32)
+        steps = jnp.asarray([(hi - lo + 2 * hd) / (s - 1) for hd, s in zip(h, sizes)],
+                            jnp.float32)
+        return Grid(mins, steps, tuple(sizes))
+
     def points(self, dim):
         return self.mins[dim] + self.steps[dim] * jnp.arange(self.sizes[dim])
 
     def interpolate(self, X):
-        """Sparse W: (indices, values) each (n, 4^d)."""
+        """Sparse W: (indices, values) each (n, 4^d).  Every row's taps are
+        consecutive in each dimension and inside the grid: all rows share
+        one stencil, as ``InterpolatedOperator`` requires."""
         n, d = X.shape
         idx_list, w_list = [], []
         for dim in range(d):
@@ -115,6 +141,10 @@ class SKI:
     # has no fused kernel — True falls back to the unfused loop.  None
     # follows ``settings.fuse_cg``.
     fuse_cg: bool | None = None
+    # learned feature map (deep kernel learning): None interpolates the raw
+    # inputs on a grid fitted to them; a FeatureMap interpolates its scaled
+    # output on a static grid_size^dims grid over its bounds
+    features: FeatureMap | None = None
 
     def __post_init__(self):
         if self.precision is not None:
@@ -125,18 +155,25 @@ class SKI:
             self.settings = dataclasses.replace(self.settings, fuse_cg=self.fuse_cg)
 
     def init_params(self, X, key=None):
-        d = X.shape[1]
-        return {
+        d = X.shape[1] if self.features is None else self.features.dims
+        params = {
             "raw_lengthscale": jnp.zeros((d,)) + _inv_softplus(jnp.float32(0.5)),
             "raw_outputscale": _inv_softplus(jnp.float32(1.0)),
             "raw_noise": _inv_softplus(jnp.float32(0.1)),
         }
+        if self.features is not None:
+            key = jax.random.PRNGKey(7) if key is None else key
+            params["net"] = self.features.init(key, X.shape[1])
+        return params
 
     def prepare_inputs(self, X):
         """Precompute geometry (grid + W) — independent of hyperparameters.
 
         This is SKI's ``data`` in the GPModel protocol: every downstream
-        method takes this geometry dict where other models take X."""
+        method takes this geometry dict where other models take X.  With a
+        feature map W depends on the network, so the data is X itself."""
+        if self.features is not None:
+            return X
         d = X.shape[1]
         grid = Grid.fit(X, (self.grid_size,) * d)
         indices, values = grid.interpolate(X)
@@ -161,9 +198,38 @@ class SKI:
             return factors[0]
         return KroneckerOperator(tuple(factors))
 
-    def operator(self, params, geom):
+    def _geometry(self, params, data):
+        """(grid, indices, values, place): the grid, the training rows'
+        interpolation weights, and the map that puts new inputs where the
+        grid sees them (the identity on raw inputs; with a feature map, the
+        network and the training features' ScaleToBounds)."""
+        if self.features is None:
+            return data["grid"], data["indices"], data["values"], lambda Xs: Xs
+        fm = self.features
+        grid = Grid.over_bounds(fm.bounds, (self.grid_size,) * fm.dims)
+        # the network follows the precision policy: under "highest" its f32
+        # matmuls take full f32 precision (one bf16 pass is the TPU default)
+        policy = (jax.default_matmul_precision("highest")
+                  if self.settings.precision == "highest" else contextlib.nullcontext())
+        with jax.named_scope("dkl.features"), policy:
+            Z = fm(params["net"], data)
+            lo, hi = jnp.min(Z), jnp.max(Z)
+            Z = scale_to_bounds(Z, lo, hi, fm.bounds)
+        with jax.named_scope("ski.interp"):
+            indices, values = grid.interpolate(Z)
+
+        def place(Xs):
+            # as ScaleToBounds in eval mode: the training features' min and
+            # max, new features clamped to them
+            with jax.named_scope("dkl.features"), policy:
+                return scale_to_bounds(jnp.clip(fm(params["net"], Xs), lo, hi), lo, hi, fm.bounds)
+
+        return grid, indices, values, place
+
+    def operator(self, params, data):
+        grid, indices, values, _ = self._geometry(params, data)
         base = InterpolatedOperator(
-            indices=geom["indices"], values=geom["values"], base=self._kuu(params, geom["grid"])
+            indices=indices, values=values, base=self._kuu(params, grid)
         )
         return AddedDiagOperator(base, _softplus(params["raw_noise"]))
 
@@ -184,12 +250,11 @@ class SKI:
         """SKI cross-covariance machinery for a test block: returns
         (KXs (n, s), kss (s,)) — k(x*, X) ≈ W* K_UU Wᵀ interpolated on the
         same grid as training."""
-        kuu = self._kuu(params, geom["grid"])
-        s_idx, s_val = geom["grid"].interpolate(Xstar)
+        grid, indices, values, place = self._geometry(params, geom)
+        kuu = self._kuu(params, grid)
+        s_idx, s_val = grid.interpolate(place(Xstar))
         star_op = InterpolatedOperator(indices=s_idx, values=s_val, base=kuu)
-        train_op = InterpolatedOperator(
-            indices=geom["indices"], values=geom["values"], base=kuu
-        )
+        train_op = InterpolatedOperator(indices=indices, values=values, base=kuu)
         KXs = train_op._W_matmul(
             kuu.matmul(star_op._Wt_matmul(jnp.eye(Xstar.shape[0])))
         )

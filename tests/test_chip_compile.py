@@ -17,6 +17,8 @@ The persistent compile cache is off around these compiles (an entry
 compiled for an absent chip cannot be read back).
 """
 
+import re
+
 import pytest
 
 import jax
@@ -231,3 +233,38 @@ def test_pallas_mll_gradient_compiles(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     # the backward streams panels: nowhere near a dense 8.4 GB K
     assert mem.temp_size_in_bytes < 2 * 1024**3, mem
+
+
+def test_dkl_ski_mll_gradient_compiles(one_chip):
+    """Deep-kernel KISS-GP at GPyTorch's Elevators setting (n=16 599, d=18,
+    feature net 18-1000-500-50-2, a 100 x 100 grid, q=16): the MLL gradient
+    compiles for the chip with W rebuilt from the features inside the
+    step.  Its temporaries stay far under 2 GiB, where one (n, q, q, m)
+    stack of K_UU rows (the unstructured diagonal for the preconditioner)
+    alone would take 170 GB, and the device ops carry the three names the
+    benchmark reads: dkl.features, ski.interp and ski.grid."""
+    from repro.core import BBMMSettings
+    from repro.gp import SKI, FeatureMap
+
+    n, d, widths = 16_599, 18, (1000, 500, 50, 2)
+    gp = SKI(grid_size=100, features=FeatureMap(widths=widths),
+             settings=BBMMSettings(num_probes=T - 1, precond_rank=5))
+    sizes = (d, *widths)
+    params = {
+        "net": [{"w": one_chip((a, b)), "b": one_chip((b,))}
+                for a, b in zip(sizes[:-1], sizes[1:])],
+        "raw_lengthscale": one_chip((2,)),
+        "raw_outputscale": one_chip(()),
+        "raw_noise": one_chip(()),
+    }
+    key = one_chip((2,), jnp.uint32)
+    compiled = jax.jit(jax.value_and_grad(gp.loss)).lower(
+        params, one_chip((n, d)), one_chip((n,)), key
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * 1024**3, mem
+    paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    for scope in ("dkl.features", "ski.interp", "ski.grid"):
+        # a path component, bare or under a transformation: "jvp(ski.grid)"
+        inside = re.compile(rf"[/(]{re.escape(scope)}[)/]")
+        assert any(inside.search(p) for p in paths), scope
